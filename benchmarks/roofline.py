@@ -95,13 +95,13 @@ def kernel_bandwidth(fast: bool = False, smoke: bool = False) -> dict:
     NP = B * pps                              # pool sized to touched pages
     key = jax.random.PRNGKey(0)
     kq, kk = jax.random.split(key)
-    kp = jax.random.normal(kk, (NP, page, KH, D), jnp.float32)
+    kp = jax.random.normal(kk, (NP, KH, page, D), jnp.float32)
     vp = kp * 0.5
     tables = jnp.arange(NP, dtype=jnp.int32).reshape(B, pps)
     lens = jnp.full((B,), S, jnp.int32)       # full: every page is read
     q = jax.random.normal(kq, (B, H, D), jnp.float32)
     Kt = 16
-    k_tail = jax.random.normal(kq, (B, Kt, KH, D), jnp.float32)
+    k_tail = jax.random.normal(kq, (B, KH, Kt, D), jnp.float32)
     v_tail = k_tail * 0.5
     tail_lens = jnp.full((B,), Kt, jnp.int32)
     C = 64 if reduced else 128                # prefill chunk

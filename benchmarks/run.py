@@ -41,6 +41,7 @@ from benchmarks import (api_stream, autoscale, batch_mode, chaos_soak,
                         concurrency, decode_loop, engine_step, external_api,
                         hot_pool, prefix_cache, qos_preemption, rate_sweep,
                         roofline, spec_decode, tp_decode)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "rate_sweep": rate_sweep.main,
@@ -76,6 +77,7 @@ def main() -> None:
                          "only, minimal sizes (implies --fast)")
     ap.add_argument("--only", default=None, choices=[*SUITES, None])
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.only:
         names = [args.only]

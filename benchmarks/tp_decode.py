@@ -16,9 +16,10 @@ speedup:
 * the sharded/single throughput ratio is recorded as an artifact trend
   line — no floor is enforced.
 
-Needs >= 4 visible devices. When run via ``benchmarks.run`` (where jax
-already initialized single-device), ``main`` re-execs this module as a
-subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+Needs >= 4 visible devices: run directly (``python -m
+benchmarks.tp_decode`` fakes 8 CPU devices before jax initializes), or via
+``benchmarks.run`` with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+in the environment. With fewer devices it stops and says so.
 
 Writes ``results/benchmarks/tp_decode.json`` (``.fast.json`` under
 --fast/--smoke).
@@ -28,7 +29,6 @@ from __future__ import annotations
 import copy
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -147,26 +147,12 @@ def bench(gen: int) -> dict:
             "tokens_identical": True}
 
 
-def _run_self(fast: bool, smoke: bool) -> None:
-    """Re-exec under a fresh interpreter where the fake-device flag can
-    still take effect (jax in THIS process already chose its backend)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    args = [sys.executable, "-m", "benchmarks.tp_decode"]
-    if fast:
-        args.append("--fast")
-    if smoke:
-        args.append("--smoke")
-    proc = subprocess.run(args, env=env)
-    if proc.returncode != 0:
-        raise SystemExit(f"tp_decode subprocess failed ({proc.returncode})")
-
-
-def main(fast: bool = False, smoke: bool = False) -> dict | None:
+def main(fast: bool = False, smoke: bool = False) -> dict:
     if jax.device_count() < SHARDS:
-        _run_self(fast, smoke)
-        return None
+        raise SystemExit(
+            f"tp_decode needs >= {SHARDS} devices, {jax.device_count()} "
+            f"visible: set XLA_FLAGS=--xla_force_host_platform_device_count"
+            f"=8 before jax initializes (CPU), or run on {SHARDS} chips")
     gen = 32 if (smoke or fast) else 96
     out = {"arch": ARCH, "batch": SLOTS, "prompt_len": PROMPT_LEN,
            "gen_tokens": gen, "page_size": PAGE, "K": K,

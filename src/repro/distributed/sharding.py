@@ -24,6 +24,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -175,7 +176,7 @@ class ServeSharding:
       attention q/k/v and MLP columns over ``model``, wo/w2 rows over
       ``model``, MoE expert stacks over ``model`` (expert-parallel decode
       falls out of the einsum), everything small replicated.
-    * **KV** — paged pools ``(L, NP, page, KH, hd)`` and slot caches
+    * **KV** — paged pools ``(L, NP, KH, page, hd)`` and slot caches
       ``(L, B, KH, S, hd)`` shard the kv-head axis over ``model``; when the
       head count is not divisible (GQA/MQA on a wide mesh) the head_dim
       axis shards instead, and when neither divides the cache replicates.
@@ -206,9 +207,13 @@ class ServeSharding:
         return int(self.mesh.shape["model"])
 
     # -- placement (device_put, at init / upload time) ---------------------------
+    def param_shardings(self, params_shapes):
+        """NamedSharding tree for params (arrays or ShapeDtypeStructs): the
+        ``out_shardings`` that initialize them in place on the mesh."""
+        return self.rules.named(self.rules.param_specs(params_shapes))
+
     def shard_params(self, params):
-        specs = self.rules.param_specs(params)
-        return jax.device_put(params, self.rules.named(specs))
+        return jax.device_put(params, self.param_shardings(params))
 
     def _head_axes(self, kh: int, hd: int):
         """(kv-head axis, head_dim axis): kv-heads over model when
@@ -220,9 +225,9 @@ class ServeSharding:
         return None, None
 
     def pool_spec(self, shape) -> P:
-        """Paged KV pool (L, num_pages, page_size, KH, hd)."""
-        kh, hd = self._head_axes(shape[3], shape[4])
-        return P(None, None, None, kh, hd)
+        """Paged KV pool (L, num_pages, KH, page_size, hd), head-major."""
+        kh, hd = self._head_axes(shape[2], shape[4])
+        return P(None, None, kh, None, hd)
 
     def view_spec(self, shape) -> P:
         """Gathered context view (L, B, S, KH, hd): same head-axis policy
@@ -239,10 +244,11 @@ class ServeSharding:
             return P(None, None, kh, None, hd)
         return P()
 
-    def shard_pools(self, pools):
-        return {n: jax.device_put(
-            a, NamedSharding(self.mesh, self.pool_spec(a.shape)))
-            for n, a in pools.items()}
+    def pool_zeros(self, shape, dtype):
+        """A zero page pool allocated shard-by-shard on the mesh (never
+        whole on one device first)."""
+        return jnp.zeros(shape, dtype,
+                         device=NamedSharding(self.mesh, self.pool_spec(shape)))
 
     def shard_slot_cache(self, cache):
         return {n: jax.device_put(

@@ -105,8 +105,8 @@ def _paged_prefill_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (qb, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         rows = row0 + jax.lax.broadcasted_iota(
@@ -136,10 +136,11 @@ def paged_flash_prefill_fwd(q, k_pages, v_pages, block_tables, kv_lens,
                             q_starts, *, group, q_block, interpret=False):
     """q: (B, KH, R, D) with R = C * G query rows (row ``c*G+g`` is head
     group ``g`` of chunk token ``c``), padded to a q_block multiple;
-    k_pages / v_pages: (NP, page, KH, D); block_tables: (B, PPS);
-    kv_lens / q_starts: (B,) int32. Returns (B, KH, R, D)."""
+    k_pages / v_pages: (NP, KH, page, D) head-major (the decode kernels'
+    pool layout); block_tables: (B, PPS); kv_lens / q_starts: (B,) int32.
+    Returns (B, KH, R, D)."""
     B, KH, R, D = q.shape
-    NP, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     PPS = block_tables.shape[1]
     assert R % q_block == 0
     nq = R // q_block
@@ -154,10 +155,10 @@ def paged_flash_prefill_fwd(q, k_pages, v_pages, block_tables, kv_lens,
         in_specs=[
             pl.BlockSpec((1, 1, q_block, D),
                          lambda b, h, qi, pi, t, kl, qs: (b, h, qi, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, qi, pi, t, kl, qs: (t[b, pi], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, qi, pi, t, kl, qs: (t[b, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, qi, pi, t, kl, qs: (t[b, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, qi, pi, t, kl, qs: (t[b, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, q_block, D),
                                lambda b, h, qi, pi, t, kl, qs: (b, h, qi, 0)),
@@ -167,19 +168,14 @@ def paged_flash_prefill_fwd(q, k_pages, v_pages, block_tables, kv_lens,
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
     )
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-    kw = {}
-    if params_cls is not None and not interpret:
-        kw["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, R, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **kw,
     )(block_tables, kv_lens, q_starts, q, k_pages, v_pages)
 
 
@@ -201,20 +197,9 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, q_block=256,
         _flash_kernel, scale=scale, causal=causal, window=window,
         q_block=q_block, k_block=k_block, num_k_blocks=nk, seq_k=seq_k)
 
-    grid = (BKH, G, nq, nk)
-    # renamed across jax releases: CompilerParams <-> TPUCompilerParams
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-    try:
-        compiler_params = params_cls(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except TypeError:  # older naming
-        compiler_params = None
-
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(BKH, G, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, q_block, D), lambda b, g, qi, ki: (b, g, qi, 0)),
             pl.BlockSpec((1, k_block, D), lambda b, g, qi, ki: (b, ki, 0)),
@@ -228,6 +213,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, q_block=256,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -70,7 +70,7 @@ def paged_flash_prefill(q, k_pages, v_pages, block_tables, q_offset, kv_len,
     if interpret is None:
         interpret = _default_interpret()
     B, C, H, D = q.shape
-    KH = k_pages.shape[2]
+    KH = k_pages.shape[1]
     assert H % KH == 0, \
         f"query heads ({H}) must be a multiple of kv heads ({KH})"
     G = H // KH

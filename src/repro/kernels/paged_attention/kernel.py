@@ -3,9 +3,11 @@
 TPU adaptation of vLLM's PagedAttention (DESIGN.md §2): the per-sequence block
 table lives in scalar-prefetch (SMEM) and *drives the DMA schedule* — the
 BlockSpec index_map dereferences ``block_tables[b, pi]`` so each grid step
-streams exactly one KV page HBM->VMEM. Pages are large (multiples of 128
-tokens) so tiles are MXU/VPU aligned, and an online-softmax accumulator in
-VMEM scratch merges pages (flash-decoding style).
+streams exactly one KV page HBM->VMEM. The pool is head-major,
+``(NP, KH, page, D)``, so each streamed block is one ``(page, D)`` tile
+whose last two dims meet the TPU's (sublane, 128-lane) tiling; an
+online-softmax accumulator in VMEM scratch merges pages (flash-decoding
+style).
 
 Grid: (B, KH, pages_per_seq) — pages innermost for the accumulator carry.
 
@@ -46,8 +48,8 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale           # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)                # (page, D)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)                   # (page, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, page)
         pos = page_start + jax.lax.broadcasted_iota(
@@ -69,22 +71,14 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _compiler_kw(interpret, semantics):
-    # renamed across jax releases: CompilerParams <-> TPUCompilerParams
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-    if params_cls is None or interpret:
-        return {}
-    return {"compiler_params": params_cls(dimension_semantics=semantics)}
-
-
 def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
                         interpret=False):
-    """q: (B, KH, G, D); k_pages/v_pages: (NP, page, KH, D);
+    """q: (B, KH, G, D); k_pages/v_pages: (NP, KH, page, D) head-major, so
+    one (page, D) tile per grid step satisfies the TPU's (8, 128) tiling;
     block_tables: (B, PPS) int32; context_lens: (B,) int32.
     Returns (B, KH, G, D)."""
     B, KH, G, D = q.shape
-    NP, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     PPS = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
 
@@ -96,10 +90,10 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, h, pi, tables, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, pi, tables, lens: (tables[b, pi], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, pi, tables, lens: (tables[b, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, pi, tables, lens: (tables[b, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, pi, tables, lens: (tables[b, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, pi, tables, lens: (b, h, 0, 0)),
@@ -111,13 +105,13 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
     )
     # batch and kv-head grid axes are independent; the page axis carries
     # the online-softmax accumulator and must run in order
-    kw = _compiler_kw(interpret, ("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kw,
     )(block_tables, context_lens, q, k_pages, v_pages)
 
 
@@ -129,7 +123,7 @@ def _decode_tail_kernel(tables_ref, clens_ref, tlens_ref, q_ref, k_ref, v_ref,
     """One extra grid step past the pages attends the in-flight tail.
 
     The K-step fused decode loop keeps the tokens generated *this call* in
-    small (B, K, KH, D) tail buffers instead of scattering them into the
+    small (B, KH, K, D) tail buffers instead of scattering them into the
     page pool every step.  Grid step ``pi == num_pages`` folds that tail
     into the same online-softmax accumulator the page steps built, so one
     kernel launch covers committed context + uncommitted tail.
@@ -164,18 +158,18 @@ def _decode_tail_kernel(tables_ref, clens_ref, tlens_ref, q_ref, k_ref, v_ref,
 
     @pl.when(jnp.logical_and(pi < num_pages, page_start < ctx))
     def _pages():
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         pos = page_start + jax.lax.broadcasted_iota(
             jnp.int32, (q_ref.shape[2], page_size), 1)
         _online_update(k, v, pos < ctx)
 
     @pl.when(jnp.logical_and(is_tail, tlens_ref[b] > 0))
     def _tail():
-        k = kt_ref[0, :, 0].astype(jnp.float32)               # (Kt, D)
-        v = vt_ref[0, :, 0].astype(jnp.float32)
+        k = kt_ref[0, 0].astype(jnp.float32)                  # (Kt, D)
+        v = vt_ref[0, 0].astype(jnp.float32)
         j = jax.lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[2], kt_ref.shape[1]), 1)
+            jnp.int32, (q_ref.shape[2], kt_ref.shape[2]), 1)
         _online_update(k, v, j < tlens_ref[b])
 
     @pl.when(is_tail)
@@ -186,16 +180,16 @@ def _decode_tail_kernel(tables_ref, clens_ref, tlens_ref, q_ref, k_ref, v_ref,
 
 def paged_decode_tail_fwd(q, k_pages, v_pages, block_tables, context_lens,
                           k_tail, v_tail, tail_lens, *, interpret=False):
-    """q: (B, KH, G, D); k_pages/v_pages: (NP, page, KH, D);
-    k_tail/v_tail: (B, Kt, KH, D) this call's in-flight tokens;
+    """q: (B, KH, G, D); k_pages/v_pages: (NP, KH, page, D);
+    k_tail/v_tail: (B, KH, Kt, D) this call's in-flight tokens;
     block_tables: (B, PPS), context_lens / tail_lens: (B,), all int32.
     Returns (B, KH, G, D).  Position ``i`` attends committed context
     ``[0, context_lens[i])`` from the pages plus tail rows
     ``[0, tail_lens[i])`` — exactly contiguous positions
     ``[0, context_lens[i] + tail_lens[i])``."""
     B, KH, G, D = q.shape
-    NP, page, _, _ = k_pages.shape
-    Kt = k_tail.shape[1]
+    page = k_pages.shape[2]
+    Kt = k_tail.shape[2]
     PPS = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
 
@@ -206,7 +200,7 @@ def paged_decode_tail_fwd(q, k_pages, v_pages, block_tables, context_lens,
     last = PPS - 1
 
     def page_map(b, h, pi, tables, clens, tlens):
-        return (tables[b, jnp.minimum(pi, last)], 0, h, 0)
+        return (tables[b, jnp.minimum(pi, last)], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -214,12 +208,12 @@ def paged_decode_tail_fwd(q, k_pages, v_pages, block_tables, context_lens,
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, h, pi, tables, clens, tlens: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), page_map),
-            pl.BlockSpec((1, page, 1, D), page_map),
-            pl.BlockSpec((1, Kt, 1, D),
-                         lambda b, h, pi, tables, clens, tlens: (b, 0, h, 0)),
-            pl.BlockSpec((1, Kt, 1, D),
-                         lambda b, h, pi, tables, clens, tlens: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D), page_map),
+            pl.BlockSpec((1, 1, page, D), page_map),
+            pl.BlockSpec((1, 1, Kt, D),
+                         lambda b, h, pi, tables, clens, tlens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Kt, D),
+                         lambda b, h, pi, tables, clens, tlens: (b, h, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, G, D),
@@ -230,12 +224,12 @@ def paged_decode_tail_fwd(q, k_pages, v_pages, block_tables, context_lens,
             pltpu.VMEM((G, D), jnp.float32),
         ],
     )
-    kw = _compiler_kw(interpret, ("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kw,
     )(block_tables, context_lens, tail_lens, q, k_pages, v_pages,
       k_tail, v_tail)

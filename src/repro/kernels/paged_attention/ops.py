@@ -15,9 +15,16 @@ tail the same way along its token axis.
 jitted functions, so its value must be stable across calls — a per-call
 ``jax.default_backend()`` probe could flip (e.g. a test harness forcing a
 platform mid-process) and silently retrace every kernel mid-serve. The
-backend is therefore resolved ONCE, at first use, and cached in
-``_BACKEND_INTERPRET``; ``kernels_compiled()`` exposes the same answer to
-the serving layer for dispatch decisions.
+platform is therefore resolved ONCE, at first use, and cached in
+``_BACKEND_INTERPRET``: compiled kernels on ``tpu``, the interpreter on
+``cpu`` (tests), and an error on any other platform — a device that can
+neither compile the kernels nor be mistaken for a test host is never
+quietly run through the interpreter. ``kernels_compiled()`` exposes the
+same answer to the serving layer for dispatch decisions.
+
+Layouts: the page pools are head-major, ``(NP, KH, page, D)``, and so are
+the fused loop's in-flight tails, ``(B, KH, Kt, D)`` — every block the
+kernels stream is one ``(rows, D)`` tile.
 """
 from __future__ import annotations
 
@@ -25,12 +32,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                    # moved across jax releases
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.kernels.paged_attention.kernel import (paged_attention_fwd,
                                                   paged_decode_tail_fwd)
@@ -39,10 +42,16 @@ _BACKEND_INTERPRET: bool | None = None
 
 
 def _default_interpret() -> bool:
-    """Resolve (once) whether Pallas runs interpreted on this backend."""
+    """Resolve (once) whether Pallas runs interpreted on this platform:
+    compiled on ``tpu``, interpreted on ``cpu``, an error elsewhere."""
     global _BACKEND_INTERPRET
     if _BACKEND_INTERPRET is None:
-        _BACKEND_INTERPRET = jax.default_backend() != "tpu"
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"Pallas kernels compile for 'tpu' and run interpreted on "
+                f"'cpu'; platform {platform!r} supports neither")
+        _BACKEND_INTERPRET = platform == "cpu"
     return _BACKEND_INTERPRET
 
 
@@ -90,7 +99,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     """Decode attention over a paged KV cache.
 
     q: (B, H, D) one query token per sequence;
-    k_pages / v_pages: (NP, page_size, KH, D) the global page pool;
+    k_pages / v_pages: (NP, KH, page_size, D) the global page pool;
     block_tables: (B, pages_per_seq) int32 page ids (pad with 0 beyond len);
     context_lens: (B,) int32 valid token counts.
     ``interpret=None`` auto-selects once per process: compiled Pallas on
@@ -100,7 +109,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     if interpret is None:
         interpret = _default_interpret()
     B, H, D = q.shape
-    out = _paged_attention_grouped(_group(q, k_pages.shape[2]), k_pages,
+    out = _paged_attention_grouped(_group(q, k_pages.shape[1]), k_pages,
                                    v_pages, block_tables, context_lens,
                                    interpret=interpret)
     return out.reshape(B, H, D)
@@ -113,8 +122,8 @@ def _fused_decode_grouped(qr, k_pages, v_pages, block_tables, context_lens,
     qp = _pad_axis(qr, 2, _sublane(qr.dtype))
     # tail rides the kernel's sublane axis too: pad the token axis and let
     # tail_lens mask the padded rows
-    kt = _pad_axis(k_tail, 1, _sublane(k_tail.dtype))
-    vt = _pad_axis(v_tail, 1, _sublane(v_tail.dtype))
+    kt = _pad_axis(k_tail, 2, _sublane(k_tail.dtype))
+    vt = _pad_axis(v_tail, 2, _sublane(v_tail.dtype))
     out = paged_decode_tail_fwd(qp, k_pages, v_pages,
                                 block_tables.astype(jnp.int32),
                                 context_lens.astype(jnp.int32),
@@ -128,16 +137,16 @@ def fused_decode_attention(q, k_pages, v_pages, block_tables, context_lens,
     """Decode attention over committed pages + an in-flight tail buffer.
 
     The K-step fused decode loop accumulates this call's freshly generated
-    KV in (B, K, KH, D) tail buffers and defers the page-pool scatter to
+    KV in (B, KH, K, D) tail buffers and defers the page-pool scatter to
     the end of the call; position ``b`` attends pages ``[0, context_lens[b])``
     plus tail rows ``[0, tail_lens[b])``.  Shapes as ``paged_attention``
-    plus k_tail/v_tail: (B, Kt, KH, D) and tail_lens: (B,).
+    plus k_tail/v_tail: (B, KH, Kt, D) and tail_lens: (B,).
     Returns (B, H, D).
     """
     if interpret is None:
         interpret = _default_interpret()
     B, H, D = q.shape
-    out = _fused_decode_grouped(_group(q, k_pages.shape[2]), k_pages,
+    out = _fused_decode_grouped(_group(q, k_pages.shape[1]), k_pages,
                                 v_pages, block_tables, context_lens,
                                 k_tail, v_tail, tail_lens,
                                 interpret=interpret)
@@ -163,14 +172,14 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
     if interpret is None:
         interpret = _default_interpret()
     B, H, D = q.shape
-    qr = _group(q, k_pages.shape[2])
-    fn = _shard_map(
+    qr = _group(q, k_pages.shape[1])
+    heads = P(None, axis, None, None)
+    fn = shard_map(
         partial(_paged_attention_grouped, interpret=interpret),
         mesh=mesh,
-        in_specs=(P(None, axis, None, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None)),
-        out_specs=P(None, axis, None, None),
-        check_rep=False,
+        in_specs=(heads, heads, heads, P(None, None), P(None)),
+        out_specs=heads,
+        check_vma=False,
     )
     out = fn(qr, k_pages, v_pages, block_tables.astype(jnp.int32),
              context_lens.astype(jnp.int32))
@@ -185,16 +194,15 @@ def fused_decode_attention_sharded(q, k_pages, v_pages, block_tables,
     if interpret is None:
         interpret = _default_interpret()
     B, H, D = q.shape
-    qr = _group(q, k_pages.shape[2])
-    fn = _shard_map(
+    qr = _group(q, k_pages.shape[1])
+    heads = P(None, axis, None, None)
+    fn = shard_map(
         partial(_fused_decode_grouped, interpret=interpret),
         mesh=mesh,
-        in_specs=(P(None, axis, None, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None),
-                  P(None, None, axis, None), P(None, None, axis, None),
+        in_specs=(heads, heads, heads, P(None, None), P(None), heads, heads,
                   P(None)),
-        out_specs=P(None, axis, None, None),
-        check_rep=False,
+        out_specs=heads,
+        check_vma=False,
     )
     out = fn(qr, k_pages, v_pages, block_tables.astype(jnp.int32),
              context_lens.astype(jnp.int32), k_tail, v_tail,

@@ -13,16 +13,17 @@ NEG_INF = -1e30
 
 
 def gather_kv(pages, block_tables):
-    """pages: (NP, page, KH, D); block_tables: (B, PPS) -> (B, PPS*page, KH, D)."""
-    g = pages[block_tables]                   # (B, PPS, page, KH, D)
-    B, PPS, page, KH, D = g.shape
-    return g.reshape(B, PPS * page, KH, D)
+    """pages: (NP, KH, page, D) head-major pool; block_tables: (B, PPS)
+    -> the contiguous seq-major view (B, PPS*page, KH, D)."""
+    g = pages[block_tables]                   # (B, PPS, KH, page, D)
+    B, PPS, KH, page, D = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, PPS * page, KH, D)
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens):
-    """q: (B, H, D); pages: (NP, page, KH, D); returns (B, H, D)."""
+    """q: (B, H, D); pages: (NP, KH, page, D); returns (B, H, D)."""
     B, H, D = q.shape
-    KH = k_pages.shape[2]
+    KH = k_pages.shape[1]
     G = H // KH
     k = gather_kv(k_pages, block_tables)      # (B, S, KH, D)
     v = gather_kv(v_pages, block_tables)
@@ -43,8 +44,8 @@ def decode_tail_attention_ref(q, k_ctx, v_ctx, context_lens, k_tail, v_tail,
 
     q: (B, H, D); k_ctx/v_ctx: (B, S, KH, D) a contiguous view of the
     committed pages (only ``[0, context_lens[b])`` valid); k_tail/v_tail:
-    (B, Kt, KH, D) tokens generated this fused call (``[0, tail_lens[b])``
-    valid). Scores for both segments are concatenated before ONE softmax,
+    (B, KH, Kt, D) head-major tokens generated this fused call
+    (``[0, tail_lens[b])`` valid). Scores for both segments are concatenated before ONE softmax,
     so the result equals attention over the contiguous positions
     ``[0, context_lens[b] + tail_lens[b])``. Returns (B, H, D).
     """
@@ -52,12 +53,12 @@ def decode_tail_attention_ref(q, k_ctx, v_ctx, context_lens, k_tail, v_tail,
     KH = k_ctx.shape[2]
     G = H // KH
     S = k_ctx.shape[1]
-    Kt = k_tail.shape[1]
+    Kt = k_tail.shape[2]
     scale = 1.0 / math.sqrt(D)
     qr = q.reshape(B, KH, G, D).astype(jnp.float32)
     s_ctx = jnp.einsum("bhgd,bkhd->bhgk", qr,
                        k_ctx.astype(jnp.float32)) * scale
-    s_tail = jnp.einsum("bhgd,bkhd->bhgk", qr,
+    s_tail = jnp.einsum("bhgd,bhkd->bhgk", qr,
                         k_tail.astype(jnp.float32)) * scale
     m_ctx = jnp.arange(S)[None, :] < context_lens[:, None]
     m_tail = jnp.arange(Kt)[None, :] < tail_lens[:, None]
@@ -67,7 +68,7 @@ def decode_tail_attention_ref(q, k_ctx, v_ctx, context_lens, k_tail, v_tail,
     p = jax.nn.softmax(s, axis=-1)
     out = (jnp.einsum("bhgk,bkhd->bhgd", p[..., :S],
                       v_ctx.astype(jnp.float32))
-           + jnp.einsum("bhgk,bkhd->bhgd", p[..., S:],
+           + jnp.einsum("bhgk,bhkd->bhgd", p[..., S:],
                         v_tail.astype(jnp.float32)))
     return out.reshape(B, H, D).astype(q.dtype)
 

@@ -71,15 +71,6 @@ def ssd_fwd(x, a, B, C, *, chunk, interpret=False):
     nc = S // chunk
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc)
-    # renamed across jax releases: CompilerParams <-> TPUCompilerParams
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-    try:
-        compiler_params = params_cls(
-            dimension_semantics=("parallel", "arbitrary"))
-    except TypeError:
-        compiler_params = None
-
     return pl.pallas_call(
         kernel,
         grid=(BH, nc),
@@ -98,6 +89,7 @@ def ssd_fwd(x, a, B, C, *, chunk, interpret=False):
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, a, B, C)

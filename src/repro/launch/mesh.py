@@ -3,12 +3,22 @@ importing this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    through every op, as the serving and training layouts assume (pinned
+    with ``with_sharding_constraint``). ``jax.make_mesh`` otherwise types
+    the axes ``Explicit``, under which a gather from a sharded operand
+    (the vocab-sharded embedding lookup) must name its out-sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -31,4 +41,4 @@ def make_local_mesh(data: int = 1, model: int = 1):
             f"devices but only {have} are visible; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} (before jax "
             f"initializes) to simulate them, or shrink the mesh")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -2,9 +2,10 @@
 
 Brings up the real continuous-batching engine for the selected architecture
 and drives a ShareGPT-like request stream through it, reporting the paper's
-§5.1 metrics.  On this CPU container the reduced config is the default;
-``--full`` uses the full config (TPU-sized — expect it to be slow/OOM off
-target hardware, it exists so the same entry point works on a real pod).
+§5.1 metrics.  The reduced config is the default (CPU tests and smoke
+runs); ``--full`` uses the published config, sized for a TPU. On a TPU the
+paged backend serves through the compiled Pallas kernels; on the CPU it
+serves through the jnp references.
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ from repro.api import StreamAssembler, to_inference_request
 from repro.api.schemas import CompletionRequest
 from repro.configs import REGISTRY, get_config, list_archs, reduced
 from repro.data.workload import make_workload, token_ids_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.serving.engine import ContinuousBatchingEngine, EngineConfig
 
@@ -44,6 +46,7 @@ def main() -> None:
                     help="subscribe every request to the token stream and "
                          "report client-observed TTFT/ITL")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else reduced(REGISTRY[args.arch])
     if cfg.family in ("ssm", "hybrid") and args.backend == "paged":

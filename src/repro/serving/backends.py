@@ -5,12 +5,13 @@ SlotBackend  — contiguous per-slot KV/state cache, works for every family
                ``max_slots``; prefill fills one slot, decode steps all slots.
 PagedBackend — vLLM-style paged KV pool with block tables, for attention
                families; decode attention goes through the paged-attention
-               path. ``use_kernel=True`` picks the no-per-step-gather hot
-               path: compiled Pallas kernels on TPU (shard_map'd over the
-               kv-head axis under a mesh), and on other backends an "XLA
-               twin" with the same memory-traffic structure — a cached
-               contiguous context view plus per-call tail buffers instead
-               of a full page gather and pool scatter every step.
+               path. On TPU it takes the no-per-step-gather hot path by
+               default: compiled Pallas kernels (shard_map'd over the
+               kv-head axis under a mesh). On CPU the jnp references serve
+               unless ``use_kernel=True`` asks for the "XLA twin", which has
+               the same memory-traffic structure — a cached contiguous
+               context view plus per-call tail buffers instead of a full
+               page gather and pool scatter every step.
 
 Both backends expose two decode paths:
 
@@ -583,7 +584,8 @@ class PagedBackend:
 
     def __init__(self, model: LM, params, *, max_slots: int, max_len: int,
                  page_size: int = 128, num_pages: int | None = None,
-                 use_kernel: bool = False, enable_prefix_cache: bool = False,
+                 use_kernel: bool | None = None,
+                 enable_prefix_cache: bool = False,
                  mesh=None):
         cfg = model.cfg
         assert cfg.family in ATTENTION_FAMILIES, \
@@ -599,33 +601,40 @@ class PagedBackend:
             num_pages = max_slots * self.pages_per_seq + 1  # +1: trash page 0
         self.kv = PagedKVCache(num_pages, page_size,
                                enable_prefix_cache=enable_prefix_cache)
-        L, KH, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+        # head-major pages: the kernels stream one (page, hd) tile per
+        # (page, kv head)
+        pool_shape = (cfg.num_layers, num_pages, cfg.num_kv_heads,
+                      page_size, cfg.head_dim)
         dtype = jnp.dtype(cfg.param_dtype)
-        self.pools = {
-            "k": jnp.zeros((L, num_pages, page_size, KH, hd), dtype),
-            "v": jnp.zeros((L, num_pages, page_size, KH, hd), dtype),
-        }
         self.shard = ServeSharding(mesh, cfg) if mesh is not None else None
-        if self.shard is not None:
+        if self.shard is None:
+            self.pools = {n: jnp.zeros(pool_shape, dtype) for n in "kv"}
+        else:
             # pages shard along the kv-head axis; the host-side allocator
             # (tables, refcounts, prefix index) is one copy serving every
             # shard — see PagedKVCache's docstring
             self.params = self.shard.shard_params(params)
-            self.pools = self.shard.shard_pools(self.pools)
-        self.use_kernel = use_kernel
-        # Kernel dispatch. GSPMD cannot partition a Pallas kernel body, so
+            self.pools = {n: self.shard.pool_zeros(pool_shape, dtype)
+                          for n in "kv"}
+        # Kernel dispatch. ``use_kernel=None`` resolves from the platform:
+        # on TPU the compiled Pallas kernels serve, on CPU the jnp
+        # references. GSPMD cannot partition a Pallas kernel body, so
         # under a mesh the kernels run per-shard via shard_map over the
         # kv-head axis — only possible when the head count divides the
-        # model axis; otherwise the sharded jnp reference serves. Where
-        # compiled Pallas is unavailable (non-TPU), the fused decode loop
-        # runs the "XLA twin": same no-per-step-gather/scatter structure
+        # model axis; otherwise the sharded jnp reference serves. On CPU
+        # an explicit ``use_kernel=True`` runs the fused decode loop as
+        # the "XLA twin": same no-per-step-gather/scatter structure
         # (cached context view + tail buffers + one deferred commit), jnp
-        # ops instead of a kernel.
-        self._kernel_sharded = use_kernel and shardable_kv_heads(
-            cfg.num_kv_heads, mesh)
-        self._fused_use_pallas = (use_kernel and kernels_compiled()
-                                  and (mesh is None or self._kernel_sharded))
-        self._needs_view = use_kernel and not self._fused_use_pallas
+        # ops instead of a kernel. The twin is never chosen on a TPU.
+        compiled = use_kernel is not False and kernels_compiled()
+        self.use_kernel = compiled if use_kernel is None else use_kernel
+        kernel_fits = mesh is None or shardable_kv_heads(cfg.num_kv_heads,
+                                                         mesh)
+        self._kernel_sharded = (self.use_kernel and mesh is not None
+                                and kernel_fits)
+        self._fused_use_pallas = self.use_kernel and compiled and kernel_fits
+        self._needs_view = self.use_kernel and not compiled
+        self._fused_tail_path = self._fused_use_pallas or self._needs_view
         self._ctx_view = None       # gathered (L, B, S, KH, hd) ctx view
         self._gather_view = jax.jit(self._gather_view_impl)
         self.free_slots = list(range(max_slots - 1, -1, -1))
@@ -718,8 +727,10 @@ class PagedBackend:
             lp, kp, vp = xs
             h2, (k, v), _ = _block(h, lp, cfg, positions, moe_mode="dense",
                                    return_kv=True)
-            kpg = k[0].reshape(n_pages, self.page_size, *k.shape[2:])
-            vpg = v[0].reshape(n_pages, self.page_size, *v.shape[2:])
+            kpg = k[0].reshape(n_pages, self.page_size,
+                               *k.shape[2:]).transpose(0, 2, 1, 3)
+            vpg = v[0].reshape(n_pages, self.page_size,
+                               *v.shape[2:]).transpose(0, 2, 1, 3)
             kp = kp.at[table].set(kpg.astype(kp.dtype))
             vp = vp.at[table].set(vpg.astype(vp.dtype))
             return h2, (kp, vp)
@@ -753,9 +764,9 @@ class PagedBackend:
             lp, kp, vp = xs
 
             def write_attend(q, k, v):
-                kp2 = kp.at[write_pages, write_offs].set(
+                kp2 = kp.at[write_pages, :, write_offs].set(
                     k[0].astype(kp.dtype))
-                vp2 = vp.at[write_pages, write_offs].set(
+                vp2 = vp.at[write_pages, :, write_offs].set(
                     v[0].astype(vp.dtype))
                 a = self._prefill_attend(q, kp2, vp2, table[None],
                                          start, kv_len)
@@ -787,8 +798,8 @@ class PagedBackend:
             lp, kp, vp = xs
             xa = rms_norm(h, lp["norm1"], cfg.norm_eps)
             q, k, v = project_qkv(xa, lp["attn"], cfg, positions)
-            kp = kp.at[page_idx, off].set(k[:, 0].astype(kp.dtype))
-            vp = vp.at[page_idx, off].set(v[:, 0].astype(vp.dtype))
+            kp = kp.at[page_idx, :, off].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[page_idx, :, off].set(v[:, 0].astype(vp.dtype))
             a = self._attend(q[:, 0], kp, vp, tables, lens + 1)  # (B,H,hd)
             h = h + (a.reshape(B, 1, -1) @ lp["attn"]["wo"])
             g = rms_norm(h, lp["norm2"], cfg.norm_eps)
@@ -1002,7 +1013,7 @@ class PagedBackend:
         """K fused decode steps with no per-step page gather or scatter.
 
         The loop body never touches the page pool: each step appends its
-        new KV to (L, B, K, KH, hd) tail buffers and attends committed
+        new KV to head-major (L, B, KH, K, hd) tail buffers and attends committed
         context + tail under ONE softmax — via the Pallas decode-tail
         kernel reading pages directly (TPU; shard_map'd over kv heads on a
         mesh, ``view`` is None), or via the cached contiguous ``view``
@@ -1031,8 +1042,8 @@ class PagedBackend:
                 lp, kc, vc, kt, vt = xs
                 xa = rms_norm(h, lp["norm1"], cfg.norm_eps)
                 q, k, v = project_qkv(xa, lp["attn"], cfg, positions)
-                kt = kt.at[bidx, written].set(k[:, 0].astype(dt))
-                vt = vt.at[bidx, written].set(v[:, 0].astype(dt))
+                kt = kt.at[bidx, :, written].set(k[:, 0].astype(dt))
+                vt = vt.at[bidx, :, written].set(v[:, 0].astype(dt))
                 if view is not None:
                     a = decode_tail_attention_ref(q[:, 0], kc, vc, lens0,
                                                   kt, vt, tail_lens)
@@ -1074,8 +1085,8 @@ class PagedBackend:
 
         k_tails, v_tails, tokens, n_gen, done, produced, out = lax.fori_loop(
             0, K, step,
-            (jnp.zeros((L, B, K, KH, hd), dt),
-             jnp.zeros((L, B, K, KH, hd), dt),
+            (jnp.zeros((L, B, KH, K, hd), dt),
+             jnp.zeros((L, B, KH, K, hd), dt),
              st["tokens"], st["n_gen"], jnp.zeros((B,), bool),
              jnp.zeros((B,), jnp.int32), jnp.zeros((K, B), jnp.int32)))
 
@@ -1090,7 +1101,8 @@ class PagedBackend:
         off = pos % ps
 
         def commit(pool_l, tail_l):
-            return pool_l.at[page_idx, off].set(tail_l, mode="drop")
+            return pool_l.at[page_idx, :, off].set(
+                tail_l.transpose(0, 2, 1, 3), mode="drop")
 
         pools = self._pin_pools(
             {"k": jax.vmap(commit)(pools["k"], k_tails),
@@ -1101,7 +1113,8 @@ class PagedBackend:
             brow = jnp.arange(B)[:, None]
 
             def advance(view_l, tail_l):
-                return view_l.at[brow, posv].set(tail_l, mode="drop")
+                return view_l.at[brow, posv].set(
+                    tail_l.transpose(0, 2, 1, 3), mode="drop")
 
             view = {"k": jax.vmap(advance)(view["k"], k_tails),
                     "v": jax.vmap(advance)(view["v"], v_tails)}
@@ -1134,7 +1147,7 @@ class PagedBackend:
         if K_eff not in self._fused:
             # tables are NOT donated: the device copy is reused across
             # calls until the allocator bumps table_version
-            if self.use_kernel:
+            if self._fused_tail_path:
                 self._fused[K_eff] = jax.jit(
                     partial(self._fused_kernel_impl, K=K_eff),
                     donate_argnums=(1, 2, 3, 5))
@@ -1143,7 +1156,7 @@ class PagedBackend:
                     partial(self._fused_impl, K=K_eff),
                     donate_argnums=(1, 2, 4))
         tables_d, lens_d = self._dev_tables
-        if self.use_kernel:
+        if self._fused_tail_path:
             if self._needs_view and self._ctx_view is None:
                 self._ctx_view = self._gather_view(self.pools, tables_d)
             (out, produced, done, self.pools, self._ctx_view, self._dec_st,
@@ -1261,8 +1274,8 @@ class PagedBackend:
             lp, kp, vp = xs
 
             def write_attend(q, k, v):
-                kp2 = kp.at[page_idx, off].set(k.astype(kp.dtype))
-                vp2 = vp.at[page_idx, off].set(v.astype(vp.dtype))
+                kp2 = kp.at[page_idx, :, off].set(k.astype(kp.dtype))
+                vp2 = vp.at[page_idx, :, off].set(v.astype(vp.dtype))
                 kg = gather_kv(kp2, tables)
                 vg = gather_kv(vp2, tables)
                 a = _spec_block_attention(q, kg, vg, lens, kv_major=False)

@@ -78,7 +78,10 @@ class EngineConfig:
     backend: str = "slots"            # slots | paged
     page_size: int = 64
     num_pages: int | None = None
-    use_kernel: bool = False
+    # paged attention kernels: None resolves from the platform (compiled
+    # Pallas on TPU, jnp references on CPU); True on CPU selects the
+    # interpreted kernels / XLA twin, the tests' parity axis
+    use_kernel: bool | None = None
     # tensor-parallel serving: a jax.sharding.Mesh with a "model" axis (see
     # launch.mesh.make_local_mesh). Params are TP-sharded via ShardingRules,
     # KV pools/caches shard along the kv-head axis, sampling state stays

@@ -92,8 +92,8 @@ def test_paged_attention(case, dtype):
     B, H, KH, D, page, PPS, NP = case
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KH, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KH, D), dtype)
+    kp = jax.random.normal(ks[1], (NP, KH, page, D), dtype)
+    vp = jax.random.normal(ks[2], (NP, KH, page, D), dtype)
     tables = jax.random.randint(ks[3], (B, PPS), 0, NP)
     lens = jax.random.randint(ks[4], (B,), 1, PPS * page + 1)
     out = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -109,8 +109,8 @@ def test_paged_attention_gqa_group_padding_is_invisible():
     B, H, KH, D, page, PPS, NP = 2, 6, 2, 64, 128, 3, 8   # G=3 -> pads to 8
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
     tables = jax.random.randint(ks[3], (B, PPS), 0, NP)
     lens = jax.random.randint(ks[4], (B,), 1, PPS * page + 1)
     out = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -121,8 +121,8 @@ def test_paged_attention_gqa_group_padding_is_invisible():
 
 def test_paged_attention_rejects_ragged_grouping():
     with pytest.raises(AssertionError, match="multiple of kv heads"):
-        paged_attention(jnp.zeros((1, 6, 64)), jnp.zeros((4, 128, 4, 64)),
-                        jnp.zeros((4, 128, 4, 64)),
+        paged_attention(jnp.zeros((1, 6, 64)), jnp.zeros((4, 4, 128, 64)),
+                        jnp.zeros((4, 4, 128, 64)),
                         jnp.zeros((1, 2), jnp.int32),
                         jnp.ones((1,), jnp.int32), interpret=True)
 
@@ -133,8 +133,8 @@ def test_paged_attention_page_permutation_invariance():
     B, H, KH, D, page, PPS, NP = 2, 8, 2, 64, 128, 4, 16
     ks = jax.random.split(jax.random.PRNGKey(2), 5)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
     tables = jax.random.randint(ks[3], (B, PPS), 0, NP)
     lens = jnp.array([page * PPS, page * 2 + 17])
     out1 = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -163,8 +163,8 @@ def test_paged_attention_edge_lengths(case, dtype):
     B, H, KH, D, NP = len(lens), 6, 2, 32, 8
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KH, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KH, D), dtype)
+    kp = jax.random.normal(ks[1], (NP, KH, page, D), dtype)
+    vp = jax.random.normal(ks[2], (NP, KH, page, D), dtype)
     tables = jnp.arange(B * PPS, dtype=jnp.int32).reshape(B, PPS) % NP
     lens = jnp.asarray(lens, jnp.int32)
     out = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -182,8 +182,8 @@ def test_paged_attention_empty_context_is_finite():
     B, H, KH, D, page, PPS, NP = 2, 4, 2, 32, 16, 2, 8
     ks = jax.random.split(jax.random.PRNGKey(12), 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
     tables = jnp.arange(B * PPS, dtype=jnp.int32).reshape(B, PPS)
     lens = jnp.asarray([0, 20], jnp.int32)
     out = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -212,10 +212,10 @@ def test_fused_decode_attention(case, dtype):
     B, H, KH, D, page, PPS, NP, Kt = case
     ks = jax.random.split(jax.random.PRNGKey(21), 6)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KH, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KH, D), dtype)
-    kt = jax.random.normal(ks[3], (B, Kt, KH, D), dtype)
-    vt = jax.random.normal(ks[4], (B, Kt, KH, D), dtype)
+    kp = jax.random.normal(ks[1], (NP, KH, page, D), dtype)
+    vp = jax.random.normal(ks[2], (NP, KH, page, D), dtype)
+    kt = jax.random.normal(ks[3], (B, KH, Kt, D), dtype)
+    vt = jax.random.normal(ks[4], (B, KH, Kt, D), dtype)
     tables = jnp.arange(B * PPS, dtype=jnp.int32).reshape(B, PPS) % NP
     lens = jax.random.randint(ks[5], (B,), 0, PPS * page + 1)
     tail_lens = (jnp.arange(B, dtype=jnp.int32) * Kt // max(B - 1, 1)) \
@@ -236,10 +236,10 @@ def test_fused_decode_attention_equals_materialized_pages():
     B, H, KH, D, page, PPS, NP, Kt = 2, 8, 2, 64, 16, 4, 32, 4
     ks = jax.random.split(jax.random.PRNGKey(22), 6)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
-    kt = jax.random.normal(ks[3], (B, Kt, KH, D))
-    vt = jax.random.normal(ks[4], (B, Kt, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
+    kt = jax.random.normal(ks[3], (B, KH, Kt, D))
+    vt = jax.random.normal(ks[4], (B, KH, Kt, D))
     # disjoint tables so the committed tails can't collide across rows
     tables = jnp.arange(1, 1 + B * PPS, dtype=jnp.int32).reshape(B, PPS)
     lens = jnp.asarray([13, 32], jnp.int32)   # mid-page and page-boundary
@@ -251,8 +251,8 @@ def test_fused_decode_attention_equals_materialized_pages():
         for j in range(int(tail_lens[b])):
             pos = int(lens[b]) + j
             pid = int(tables[b, pos // page])
-            kp2 = kp2.at[pid, pos % page].set(kt[b, j])
-            vp2 = vp2.at[pid, pos % page].set(vt[b, j])
+            kp2 = kp2.at[pid, :, pos % page].set(kt[b, :, j])
+            vp2 = vp2.at[pid, :, pos % page].set(vt[b, :, j])
     ref = paged_attention_ref(q, kp2, vp2, tables, lens + tail_lens)
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -277,8 +277,8 @@ def test_paged_flash_prefill(case, dtype):
     B, C, H, KH, D, page, PPS, NP, start = case
     ks = jax.random.split(jax.random.PRNGKey(31), 3)
     q = jax.random.normal(ks[0], (B, C, H, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KH, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KH, D), dtype)
+    kp = jax.random.normal(ks[1], (NP, KH, page, D), dtype)
+    vp = jax.random.normal(ks[2], (NP, KH, page, D), dtype)
     tables = jnp.arange(B * PPS, dtype=jnp.int32).reshape(B, PPS) % NP
     kv_len = start + C
     assert kv_len <= PPS * page
@@ -309,8 +309,8 @@ def test_paged_attention_sharded_matches_unsharded(mesh4):
     B, H, KH, D, page, PPS, NP = 2, 16, 8, 32, 16, 4, 32
     ks = jax.random.split(jax.random.PRNGKey(41), 5)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
     tables = jax.random.randint(ks[3], (B, PPS), 0, NP)
     lens = jax.random.randint(ks[4], (B,), 1, PPS * page + 1)
     ref = paged_attention(q, kp, vp, tables, lens, interpret=True)
@@ -323,10 +323,10 @@ def test_fused_decode_attention_sharded_matches_unsharded(mesh4):
     B, H, KH, D, page, PPS, NP, Kt = 2, 8, 4, 32, 16, 2, 16, 4
     ks = jax.random.split(jax.random.PRNGKey(42), 6)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NP, page, KH, D))
-    vp = jax.random.normal(ks[2], (NP, page, KH, D))
-    kt = jax.random.normal(ks[3], (B, Kt, KH, D))
-    vt = jax.random.normal(ks[4], (B, Kt, KH, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
+    kt = jax.random.normal(ks[3], (B, KH, Kt, D))
+    vt = jax.random.normal(ks[4], (B, KH, Kt, D))
     tables = jax.random.randint(ks[5], (B, PPS), 0, NP)
     lens = jnp.asarray([16, 9], jnp.int32)
     tail_lens = jnp.asarray([2, 4], jnp.int32)

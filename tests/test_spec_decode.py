@@ -214,7 +214,7 @@ def _gather_seq_kv(eng, rid):
         kp = np.asarray(be.pools["k"])
         vp = np.asarray(be.pools["v"])
         ps = be.page_size
-        rows = [np.stack([pool[:, table[p // ps], p % ps]
+        rows = [np.stack([pool[:, table[p // ps], :, p % ps]
                           for p in range(n)], 1) for pool in (kp, vp)]
         return n, rows
     s = be.slot(rid)                                    # dense slots

@@ -135,9 +135,9 @@ def test_paged_placement_mha_heads_sharded(qwen, mesh4, engine_factory,
     assert _spec(layers["attn"]["wq"], 3)[-1] == "model"
     assert _spec(layers["mlp"]["w1"], 3)[-1] == "model"
     assert _spec(layers["attn"]["wo"], 3)[-2] == "model"
-    # 4 kv heads / 4 shards: the pool (L, NP, page, KH, hd) splits on KH
-    assert _spec(be.pools["k"], 5) == (None, None, None, "model", None)
-    assert _spec(be.pools["v"], 5) == (None, None, None, "model", None)
+    # 4 kv heads / 4 shards: the pool (L, NP, KH, page, hd) splits on KH
+    assert _spec(be.pools["k"], 5) == (None, None, "model", None, None)
+    assert _spec(be.pools["v"], 5) == (None, None, "model", None, None)
     _assert_sampler_state_replicated(be)
 
 
