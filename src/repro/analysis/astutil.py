@@ -5,7 +5,8 @@ Rules resolve names *canonically* (``np.asarray`` -> ``numpy.asarray``,
 two hot-path rules share :class:`JitRegistry` — the per-module inventory
 of which local functions are jitted (and with which ``donate_argnums``),
 whether via decorator, ``jax.jit(f, ...)`` assignment, or a
-``partial(...)`` wrapper.
+``partial(...)`` wrapper (also inside a naming wrapper
+``jax.jit(g("name", partial(f, ...)))``).
 """
 from __future__ import annotations
 
@@ -100,12 +101,23 @@ class JitTarget:
     node: ast.AST                # registration site (for diagnostics)
 
 
+def _is_naming_call(node: ast.Call) -> bool:
+    """``g("name", f)``: a wrapper that names the program jitted from f."""
+    return (len(node.args) == 2 and not node.keywords
+            and isinstance(node.args[0], ast.Constant)
+            and isinstance(node.args[0].value, str))
+
+
 def _unwrap_partial(imports: ImportMap, node: ast.AST) -> ast.AST:
-    """partial(f, ...) / functools.partial(f, ...) -> f (recursively)."""
-    while (isinstance(node, ast.Call)
-           and resolves_to(imports, node.func, "functools.partial")
-           and node.args):
-        node = node.args[0]
+    """partial(f, ...) / functools.partial(f, ...) -> f, and a naming
+    wrapper ``g("name", f)`` -> f (recursively)."""
+    while isinstance(node, ast.Call) and node.args:
+        if resolves_to(imports, node.func, "functools.partial"):
+            node = node.args[0]
+        elif _is_naming_call(node):
+            node = node.args[1]
+        else:
+            break
     return node
 
 

@@ -36,6 +36,16 @@ the multi-token analogue of one fused step, with the same state-residency
 and zero-logits-transfer contract. ``spec_headroom``/``reset_lens`` are its
 host-side page-reservation and draft-rollback companions.
 
+In a profiler trace every jitted program carries a fixed name
+(``jit_fused_decode``, ``jit_spec_verify``, ``jit_prefill``,
+``jit_swap``, ...), the on-device sampler runs under the scope
+``sample`` and the decode attention under ``decode_attention`` (in each
+op's ``op_name`` metadata), and ``fused_decode`` marks its host phases
+with the spans ``engine.decode.prep`` (page headroom, copy-on-write,
+table and state upload, up to the dispatch) and ``engine.decode.wait``
+(from the dispatch until the tokens are on the host). With no profiler
+session active a span costs a microsecond or two of host time.
+
 Both backends speak the same prefill protocol to the engine:
 
   task = backend.start_prefill(seq_id, prompt)   # reserve slot/pages
@@ -59,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.distributed.sharding import ServeSharding
 from repro.models import LM
@@ -102,6 +113,14 @@ def _logits_to_host(x) -> np.ndarray:
     return out
 
 
+def _named(name: str, fn):
+    """``fn`` (a ``partial`` or lambda) with the name its jitted program
+    carries in HLO and in profiler traces, ``jit_<name>``: a ``partial``
+    has no name of its own and lowers as ``jit__unknown``."""
+    fn.__name__ = name
+    return fn
+
+
 def _upload_state(host_state: dict, shard: ServeSharding | None = None) -> dict:
     # copy: jnp.asarray may alias numpy memory on CPU, and the fused call
     # donates the state buffers. Sharded engines replicate the state onto
@@ -117,13 +136,14 @@ def _sample_and_latch(st, logits, tokens, n_gen, done, produced, live):
     semantics cannot diverge. ``live`` slots take the sampled token and
     advance; a live slot hitting its stop token or generation limit
     latches ``done`` and freezes from the next step on."""
-    seeds = fold_seeds(st["seed_base"], n_gen)
-    sampled = sample_from_logits(logits, st["temps"], st["top_ps"], seeds)
-    tokens = jnp.where(live, sampled, tokens)
-    n_gen = n_gen + live.astype(jnp.int32)
-    hit_stop = (st["stop_tok"] >= 0) & (sampled == st["stop_tok"])
-    done = done | (live & (hit_stop | (n_gen >= st["gen_limit"])))
-    produced = produced + live.astype(jnp.int32)
+    with jax.named_scope("sample"):
+        seeds = fold_seeds(st["seed_base"], n_gen)
+        sampled = sample_from_logits(logits, st["temps"], st["top_ps"], seeds)
+        tokens = jnp.where(live, sampled, tokens)
+        n_gen = n_gen + live.astype(jnp.int32)
+        hit_stop = (st["stop_tok"] >= 0) & (sampled == st["stop_tok"])
+        done = done | (live & (hit_stop | (n_gen >= st["gen_limit"])))
+        produced = produced + live.astype(jnp.int32)
     return tokens, n_gen, done, produced
 
 
@@ -164,21 +184,23 @@ def _spec_accept_and_latch(st, logits, draft):
     advanced by ``produced``.
     """
     T = logits.shape[1]
-    targets = spec_targets(logits, st["temps"], st["top_ps"],
-                           st["seed_base"], st["n_gen"])
-    emit, n_emit = spec_accept(targets, draft)
-    n2 = st["n_gen"][:, None] + 1 + jnp.arange(T, dtype=jnp.int32)[None, :]
-    hit_stop = (st["stop_tok"][:, None] >= 0) \
-        & (targets == st["stop_tok"][:, None])
-    hit = emit & (hit_stop | (n2 >= st["gen_limit"][:, None]))
-    any_hit = hit.any(axis=1)
-    first_hit = jnp.argmax(hit, axis=1).astype(jnp.int32)
-    produced = jnp.where(any_hit, first_hit + 1, n_emit)
-    produced = jnp.where(st["active"], produced, 0)
-    done = st["active"] & any_hit
-    last = jnp.take_along_axis(
-        targets, jnp.maximum(produced - 1, 0)[:, None], axis=1)[:, 0]
-    tokens = jnp.where(produced > 0, last, st["tokens"])
+    with jax.named_scope("sample"):
+        targets = spec_targets(logits, st["temps"], st["top_ps"],
+                               st["seed_base"], st["n_gen"])
+        emit, n_emit = spec_accept(targets, draft)
+        n2 = st["n_gen"][:, None] + 1 \
+            + jnp.arange(T, dtype=jnp.int32)[None, :]
+        hit_stop = (st["stop_tok"][:, None] >= 0) \
+            & (targets == st["stop_tok"][:, None])
+        hit = emit & (hit_stop | (n2 >= st["gen_limit"][:, None]))
+        any_hit = hit.any(axis=1)
+        first_hit = jnp.argmax(hit, axis=1).astype(jnp.int32)
+        produced = jnp.where(any_hit, first_hit + 1, n_emit)
+        produced = jnp.where(st["active"], produced, 0)
+        done = st["active"] & any_hit
+        last = jnp.take_along_axis(
+            targets, jnp.maximum(produced - 1, 0)[:, None], axis=1)[:, 0]
+        tokens = jnp.where(produced > 0, last, st["tokens"])
     st = dict(st, tokens=tokens, n_gen=st["n_gen"] + produced)
     return targets, produced, done, st
 
@@ -345,7 +367,7 @@ class SlotBackend:
                     last_index=true_len - 1, moe_mode="dense")
                 cache["len"] = jnp.full_like(cache["len"], true_len)
                 return logits, self._pin_cache(cache)
-            self._prefill[bucket] = jax.jit(fn)
+            self._prefill[bucket] = jax.jit(_named("prefill", fn))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :S] = prompt
         logits, slot_cache = self._prefill[bucket](
@@ -457,16 +479,19 @@ class SlotBackend:
         call is reused. Returns (tokens (K, max_slots) np.int32,
         produced (max_slots,) np.int32, done (max_slots,) bool).
         """
-        if host_state is not None:
-            self._dec_st = _upload_state(host_state, self.shard)
-        assert self._dec_st is not None, \
-            "fused_decode needs host_state on the first call"
-        if K not in self._fused:
-            self._fused[K] = jax.jit(partial(self._fused_impl, K=K),
-                                     donate_argnums=(1, 2))
-        out, produced, done, self.cache, self._dec_st = self._fused[K](
-            self.params, self.cache, self._dec_st)
-        return np.asarray(out), np.asarray(produced), np.asarray(done)
+        with TraceAnnotation("engine.decode.prep"):
+            if host_state is not None:
+                self._dec_st = _upload_state(host_state, self.shard)
+            assert self._dec_st is not None, \
+                "fused_decode needs host_state on the first call"
+            if K not in self._fused:
+                self._fused[K] = jax.jit(
+                    _named("fused_decode", partial(self._fused_impl, K=K)),
+                    donate_argnums=(1, 2))
+        with TraceAnnotation("engine.decode.wait"):
+            out, produced, done, self.cache, self._dec_st = self._fused[K](
+                self.params, self.cache, self._dec_st)
+            return np.asarray(out), np.asarray(produced), np.asarray(done)
 
     # -- speculative decoding ----------------------------------------------------
     @property
@@ -557,8 +582,9 @@ class SlotBackend:
             "spec_verify needs host_state on the first call"
         T = draft_tokens.shape[1] + 1
         if T not in self._spec_fns:
-            self._spec_fns[T] = jax.jit(partial(self._spec_impl, T=T),
-                                        donate_argnums=(1, 2))
+            self._spec_fns[T] = jax.jit(
+                _named("spec_verify", partial(self._spec_impl, T=T)),
+                donate_argnums=(1, 2))
         out, produced, done, self.cache, self._dec_st = self._spec_fns[T](
             self.params, self.cache, self._dec_st,
             self._put(np.ascontiguousarray(draft_tokens)))
@@ -648,10 +674,10 @@ class PagedBackend:
         self._cow = jax.jit(self._cow_impl, donate_argnums=(0,))
         # swap-in upload (preemption restore): write saved page KV back
         # into freshly allocated pages; specializes per page count
-        self._swap = jax.jit(
-            lambda pools, table, k, v: self._pin_pools({
+        self._swap = jax.jit(_named(
+            "swap", lambda pools, table, k, v: self._pin_pools({
                 "k": pools["k"].at[:, table].set(k),
-                "v": pools["v"].at[:, table].set(v)}),
+                "v": pools["v"].at[:, table].set(v)})),
             donate_argnums=(0,))
         self._fused = {}            # K -> jitted multi-step decode+sample fn
         self._spec_fns = {}         # T -> jitted verify+accept fn
@@ -800,7 +826,8 @@ class PagedBackend:
             q, k, v = project_qkv(xa, lp["attn"], cfg, positions)
             kp = kp.at[page_idx, :, off].set(k[:, 0].astype(kp.dtype))
             vp = vp.at[page_idx, :, off].set(v[:, 0].astype(vp.dtype))
-            a = self._attend(q[:, 0], kp, vp, tables, lens + 1)  # (B,H,hd)
+            with jax.named_scope("decode_attention"):
+                a = self._attend(q[:, 0], kp, vp, tables, lens + 1)  # (B,H,hd)
             h = h + (a.reshape(B, 1, -1) @ lp["attn"]["wo"])
             g = rms_norm(h, lp["norm2"], cfg.norm_eps)
             if cfg.moe:
@@ -872,7 +899,8 @@ class PagedBackend:
         write_table = write_table[:n_pages]
         if bucket not in self._prefill:
             self._prefill[bucket] = jax.jit(
-                partial(self._prefill_impl, n_pages=n_pages),
+                _named("prefill", partial(self._prefill_impl,
+                                          n_pages=n_pages)),
                 donate_argnums=(2,))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :S] = prompt
@@ -1044,16 +1072,17 @@ class PagedBackend:
                 q, k, v = project_qkv(xa, lp["attn"], cfg, positions)
                 kt = kt.at[bidx, :, written].set(k[:, 0].astype(dt))
                 vt = vt.at[bidx, :, written].set(v[:, 0].astype(dt))
-                if view is not None:
-                    a = decode_tail_attention_ref(q[:, 0], kc, vc, lens0,
-                                                  kt, vt, tail_lens)
-                elif self.shard is not None:
-                    a = fused_decode_attention_sharded(
-                        q[:, 0], kc, vc, tables, lens0, kt, vt, tail_lens,
-                        mesh=self.shard.mesh)
-                else:
-                    a = fused_decode_attention(q[:, 0], kc, vc, tables,
-                                               lens0, kt, vt, tail_lens)
+                with jax.named_scope("decode_attention"):
+                    if view is not None:
+                        a = decode_tail_attention_ref(q[:, 0], kc, vc, lens0,
+                                                      kt, vt, tail_lens)
+                    elif self.shard is not None:
+                        a = fused_decode_attention_sharded(
+                            q[:, 0], kc, vc, tables, lens0, kt, vt,
+                            tail_lens, mesh=self.shard.mesh)
+                    else:
+                        a = fused_decode_attention(q[:, 0], kc, vc, tables,
+                                                   lens0, kt, vt, tail_lens)
                 h = h + (a.reshape(B, 1, -1) @ lp["attn"]["wo"])
                 g = rms_norm(h, lp["norm2"], cfg.norm_eps)
                 if cfg.moe:
@@ -1137,42 +1166,47 @@ class PagedBackend:
         otherwise the device-resident copies carry over. Returns
         (tokens (K_eff, max_slots), produced, done) as numpy arrays.
         """
-        K_eff = self._reserve_headroom(max(1, K))
-        self._resolve_cow(K_eff)
-        self._refresh_tables(force=host_state is not None)
-        if host_state is not None:
-            self._dec_st = _upload_state(host_state, self.shard)
-        assert self._dec_st is not None, \
-            "fused_decode needs host_state on the first call"
-        if K_eff not in self._fused:
-            # tables are NOT donated: the device copy is reused across
-            # calls until the allocator bumps table_version
-            if self._fused_tail_path:
-                self._fused[K_eff] = jax.jit(
-                    partial(self._fused_kernel_impl, K=K_eff),
-                    donate_argnums=(1, 2, 3, 5))
-            else:
-                self._fused[K_eff] = jax.jit(
-                    partial(self._fused_impl, K=K_eff),
-                    donate_argnums=(1, 2, 4))
-        tables_d, lens_d = self._dev_tables
-        if self._fused_tail_path:
+        with TraceAnnotation("engine.decode.prep"):
+            K_eff = self._reserve_headroom(max(1, K))
+            self._resolve_cow(K_eff)
+            self._refresh_tables(force=host_state is not None)
+            if host_state is not None:
+                self._dec_st = _upload_state(host_state, self.shard)
+            assert self._dec_st is not None, \
+                "fused_decode needs host_state on the first call"
+            if K_eff not in self._fused:
+                # tables are NOT donated: the device copy is reused across
+                # calls until the allocator bumps table_version
+                if self._fused_tail_path:
+                    self._fused[K_eff] = jax.jit(
+                        _named("fused_decode",
+                               partial(self._fused_kernel_impl, K=K_eff)),
+                        donate_argnums=(1, 2, 3, 5))
+                else:
+                    self._fused[K_eff] = jax.jit(
+                        _named("fused_decode",
+                               partial(self._fused_impl, K=K_eff)),
+                        donate_argnums=(1, 2, 4))
+            tables_d, lens_d = self._dev_tables
             if self._needs_view and self._ctx_view is None:
                 self._ctx_view = self._gather_view(self.pools, tables_d)
-            (out, produced, done, self.pools, self._ctx_view, self._dec_st,
-             lens_d) = self._fused[K_eff](self.params, self.pools,
-                                          self._ctx_view, self._dec_st,
-                                          tables_d, lens_d)
-        else:
-            out, produced, done, self.pools, self._dec_st, lens_d = \
-                self._fused[K_eff](self.params, self.pools, self._dec_st,
-                                   tables_d, lens_d)
-        self._dev_tables = (tables_d, lens_d)
-        produced_np = np.asarray(produced)
+        with TraceAnnotation("engine.decode.wait"):
+            if self._fused_tail_path:
+                (out, produced, done, self.pools, self._ctx_view,
+                 self._dec_st, lens_d) = self._fused[K_eff](
+                    self.params, self.pools, self._ctx_view, self._dec_st,
+                    tables_d, lens_d)
+            else:
+                out, produced, done, self.pools, self._dec_st, lens_d = \
+                    self._fused[K_eff](self.params, self.pools,
+                                       self._dec_st, tables_d, lens_d)
+            self._dev_tables = (tables_d, lens_d)
+            produced_np = np.asarray(produced)
+            out, done = np.asarray(out), np.asarray(done)
         for slot, sid in self.seq_of.items():
             if sid in self.decoding:
                 self.kv.advance_n(sid, int(produced_np[slot]))
-        return np.asarray(out), produced_np, np.asarray(done)
+        return out, produced_np, done
 
     def _reserve_headroom(self, n: int) -> int:
         """Reserve page headroom for up to ``n`` token writes per decoding
@@ -1309,8 +1343,9 @@ class PagedBackend:
         assert self._dec_st is not None, \
             "spec_verify needs host_state on the first call"
         if T not in self._spec_fns:
-            self._spec_fns[T] = jax.jit(partial(self._spec_impl, T=T),
-                                        donate_argnums=(1, 2, 4))
+            self._spec_fns[T] = jax.jit(
+                _named("spec_verify", partial(self._spec_impl, T=T)),
+                donate_argnums=(1, 2, 4))
         tables_d, lens_d = self._dev_tables
         out, produced, done, self.pools, self._dec_st, lens_d = \
             self._spec_fns[T](self.params, self.pools, self._dec_st,

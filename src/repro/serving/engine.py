@@ -27,6 +27,10 @@ Throughput/latency features layered on the base loop:
   falls back to K=1 automatically whenever a prefill is in flight or the
   batch composition just changed, so chunked prefill and prefix caching
   compose unchanged; outputs are token-identical to the per-step path.
+  Each fused call that runs fewer than K steps counts under one reason in
+  ``stats``: ``k1_prefill`` (a prefill in flight), else ``k1_batch`` (the
+  batch changed), else ``k_pool`` (the backend ran fewer steps than asked:
+  page headroom or ``max_seq_len``).
 * **Speculative decoding** (``spec_tokens`` = k > 0, with a draft model):
   per round the draft's fused loop proposes k tokens and ONE batched
   target forward verifies all k+1 positions, accepting via the seeded-
@@ -46,6 +50,16 @@ Throughput/latency features layered on the base loop:
   ``preempt_swap``, by a host swap-out/in round trip that needs no
   recompute. Restored sequences keep their sampling state (seeds fold on
   ``n_gen``), so outputs stay token-identical to an uninterrupted run.
+
+For a profiler, ``step()`` writes host spans on the device trace's clock:
+``engine.step`` (a step trace annotation) around each step, holding
+``engine.admit`` for each admission and ``engine.prefill`` for each
+prefill chunk (both with the request id as ``request_id``), and
+``engine.decode`` around the step's decode call, which holds the
+backend's ``engine.decode.prep`` and ``engine.decode.wait`` and the
+engine's ``engine.decode.unpack`` (the loop over slots that emits frames
+and checks finishes). With no profiler session active a span costs a
+microsecond or two of host time.
 """
 from __future__ import annotations
 
@@ -54,6 +68,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.api.schemas import StreamDelta
 from repro.models import LM
@@ -253,7 +268,9 @@ class ContinuousBatchingEngine:
                       "spec_rounds": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "preemptions": 0, "restores": 0,
                       "restore_cached_tokens": 0, "swap_outs": 0,
-                      "swap_ins": 0}
+                      "swap_ins": 0,
+                      # fused decode calls that ran below K, by reason
+                      "k1_prefill": 0, "k1_batch": 0, "k_pool": 0}
 
     # -- queue management -------------------------------------------------------
     def add_request(self, req: InferenceRequest, on_delta=None):
@@ -460,32 +477,39 @@ class ContinuousBatchingEngine:
     def step(self) -> list[RequestOutput]:
         self.stats["steps"] += 1
         finished: list[RequestOutput] = []
+        with StepTraceAnnotation("engine.step", step_num=self.stats["steps"]):
+            # 0) policy-driven eviction (page pressure / blocked urgent
+            # head): freed pages are claimable by this same step's
+            # admissions
+            self._maybe_preempt()
 
-        # 0) policy-driven eviction (page pressure / blocked urgent head):
-        # freed pages are claimable by this same step's admissions
-        self._maybe_preempt()
-
-        # 1) prefill: whole prompts (legacy) or up to the chunk budget
-        if self.cfg.chunked_prefill_budget > 0:
-            self._prefill_chunked(finished)
-        else:
-            self._prefill_one_shot(finished)
-
-        # 2) batched decode over all running sequences
-        if self.running:
-            by_slot = {self.backend.slot(rid): run
-                       for rid, run in self.running.items()}
-            if self.draft_backend is not None and not self.prefilling:
-                # speculative round; during chunked-prefill interleave we
-                # fall back to the plain fused path (which clamps K=1) so
-                # time-between-tokens stays bounded while prompts ingest
-                self._decode_spec(by_slot, finished)
-            elif (self.cfg.fused_decode
-                    and getattr(self.backend, "supports_fused_decode", False)):
-                self._decode_fused(by_slot, finished)
+            # 1) prefill: whole prompts (legacy) or up to the chunk budget
+            if self.cfg.chunked_prefill_budget > 0:
+                self._prefill_chunked(finished)
             else:
-                self._decode_legacy(by_slot, finished)
+                self._prefill_one_shot(finished)
+
+            # 2) batched decode over all running sequences
+            if self.running:
+                by_slot = {self.backend.slot(rid): run
+                           for rid, run in self.running.items()}
+                with TraceAnnotation("engine.decode"):
+                    self._decode(by_slot, finished)
         return finished
+
+    def _decode(self, by_slot: dict, finished: list):
+        """One decode call over the running batch: a speculative round,
+        the fused path, or the legacy per-token path."""
+        if self.draft_backend is not None and not self.prefilling:
+            # speculative round; during chunked-prefill interleave we fall
+            # back to the plain fused path (which clamps K=1) so
+            # time-between-tokens stays bounded while prompts ingest
+            self._decode_spec(by_slot, finished)
+        elif (self.cfg.fused_decode
+                and getattr(self.backend, "supports_fused_decode", False)):
+            self._decode_fused(by_slot, finished)
+        else:
+            self._decode_legacy(by_slot, finished)
 
     def _decode_legacy(self, by_slot: dict, finished: list):
         """Per-token host-driven decode: logits come back to the host, a
@@ -495,16 +519,17 @@ class ContinuousBatchingEngine:
         toks = np.asarray(sample_tokens(logits, st.temps, st.top_ps,
                                         st.step_seeds()))
         self.stats["decode_syncs"] += 1
-        for s, run in by_slot.items():
-            tok = int(toks[s])
-            run.output_tokens.append(tok)
-            st.tokens[s] = tok
-            st.n_gen[s] += 1
-            self.stats["decode_tokens"] += 1
-            self._emit_delta(run, [tok])
-            f = self._maybe_finish(run)
-            if f:
-                finished.append(f)
+        with TraceAnnotation("engine.decode.unpack"):
+            for s, run in by_slot.items():
+                tok = int(toks[s])
+                run.output_tokens.append(tok)
+                st.tokens[s] = tok
+                st.n_gen[s] += 1
+                self.stats["decode_tokens"] += 1
+                self._emit_delta(run, [tok])
+                f = self._maybe_finish(run)
+                if f:
+                    finished.append(f)
 
     def _decode_fused(self, by_slot: dict, finished: list):
         """Device-resident decode: one fused jitted call runs K decode +
@@ -512,33 +537,40 @@ class ContinuousBatchingEngine:
         ids plus produced/done vectors."""
         st = self.slots
         K = max(1, self.cfg.decode_steps_per_sync)
-        if self.prefilling or st.dirty:
+        clamp = None        # stats key of why this call runs below K, if so
+        if K > 1 and (self.prefilling or st.dirty):
             # prefill in flight or batch composition changed: sync every
             # token so chunked prefill interleaves unchanged. A backlog in
             # ``waiting`` alone does NOT clamp K — queued requests can only
             # admit once a slot frees, which happens at a sync boundary
             # either way, so a saturated engine keeps the multi-step win.
+            clamp = "k1_prefill" if self.prefilling else "k1_batch"
             K = 1
         toks, produced, done = self.backend.fused_decode(
             K, st.host_state() if st.dirty else None)
+        if toks.shape[0] < K:
+            clamp = "k_pool"
+        if clamp is not None:
+            self.stats[clamp] += 1
         st.dirty = False
         self.stats["decode_syncs"] += 1
-        for s, run in by_slot.items():
-            p = int(produced[s])
-            new = [int(toks[j, s]) for j in range(p)]
-            run.output_tokens.extend(new)
-            st.tokens[s] = run.last_token
-            st.n_gen[s] += p
-            self.stats["decode_tokens"] += p
-            self._emit_delta(run, new)
-            f = self._maybe_finish(run)
-            if (f is not None) != bool(done[s]):
-                raise RuntimeError(
-                    f"fused decode divergence for {run.req.request_id}: "
-                    f"device done={bool(done[s])}, host finish="
-                    f"{f.finish_reason if f else None}")
-            if f:
-                finished.append(f)
+        with TraceAnnotation("engine.decode.unpack"):
+            for s, run in by_slot.items():
+                p = int(produced[s])
+                new = [int(toks[j, s]) for j in range(p)]
+                run.output_tokens.extend(new)
+                st.tokens[s] = run.last_token
+                st.n_gen[s] += p
+                self.stats["decode_tokens"] += p
+                self._emit_delta(run, new)
+                f = self._maybe_finish(run)
+                if (f is not None) != bool(done[s]):
+                    raise RuntimeError(
+                        f"fused decode divergence for {run.req.request_id}: "
+                        f"device done={bool(done[s])}, host finish="
+                        f"{f.finish_reason if f else None}")
+                if f:
+                    finished.append(f)
 
     def _draft_state(self) -> dict:
         """Per-slot state for the draft's proposal loop: the target's
@@ -593,27 +625,28 @@ class ContinuousBatchingEngine:
         st.dirty = False
         self.stats["decode_syncs"] += 1
         self.stats["spec_rounds"] += 1
-        for s, run in by_slot.items():
-            p = int(produced[s])
-            self.stats["spec_proposed"] += k_used
-            self.stats["spec_accepted"] += max(p - 1, 0)
-            new = [int(out[j, s]) for j in range(p)]
-            run.output_tokens.extend(new)
-            self._emit_delta(run, new)
-            st.tokens[s] = run.last_token
-            st.n_gen[s] += p
-            # the proposal loop wrote KV for exactly the accepted prefix
-            # (plus rejected rows past the rolled-back length)
-            run.draft_len = run.cache_len
-            self.stats["decode_tokens"] += p
-            f = self._maybe_finish(run)
-            if (f is not None) != bool(done[s]):
-                raise RuntimeError(
-                    f"spec decode divergence for {run.req.request_id}: "
-                    f"device done={bool(done[s])}, host finish="
-                    f"{f.finish_reason if f else None}")
-            if f:
-                finished.append(f)
+        with TraceAnnotation("engine.decode.unpack"):
+            for s, run in by_slot.items():
+                p = int(produced[s])
+                self.stats["spec_proposed"] += k_used
+                self.stats["spec_accepted"] += max(p - 1, 0)
+                new = [int(out[j, s]) for j in range(p)]
+                run.output_tokens.extend(new)
+                self._emit_delta(run, new)
+                st.tokens[s] = run.last_token
+                st.n_gen[s] += p
+                # the proposal loop wrote KV for exactly the accepted
+                # prefix (plus rejected rows past the rolled-back length)
+                run.draft_len = run.cache_len
+                self.stats["decode_tokens"] += p
+                f = self._maybe_finish(run)
+                if (f is not None) != bool(done[s]):
+                    raise RuntimeError(
+                        f"spec decode divergence for {run.req.request_id}: "
+                        f"device done={bool(done[s])}, host finish="
+                        f"{f.finish_reason if f else None}")
+                if f:
+                    finished.append(f)
 
     def run_to_completion(self) -> list[RequestOutput]:
         outs = []
@@ -625,18 +658,20 @@ class ContinuousBatchingEngine:
     def _admit(self) -> tuple[_Running, PrefillTask | None]:
         req = self.policy.pop()
         self.policy.on_admitted(req)
-        run = self._preempted.pop(req.request_id, None)
-        if run is not None:
-            return self._admit_restore(run)
-        run = _Running(req=req, metrics=req._metrics)
-        task = self.backend.start_prefill(req.request_id, req.prompt_tokens)
-        if self.draft_backend is not None:
-            # reserve the draft's slot/pages NOW so both backends see the
-            # same admit/free order (their slot indices stay equal); the
-            # draft's prompt is computed one-shot when the target's prefill
-            # completes
-            run.draft_task = self.draft_backend.start_prefill(
-                req.request_id, req.prompt_tokens)
+        with TraceAnnotation("engine.admit", request_id=req.request_id):
+            run = self._preempted.pop(req.request_id, None)
+            if run is not None:
+                return self._admit_restore(run)
+            run = _Running(req=req, metrics=req._metrics)
+            task = self.backend.start_prefill(req.request_id,
+                                              req.prompt_tokens)
+            if self.draft_backend is not None:
+                # reserve the draft's slot/pages NOW so both backends see
+                # the same admit/free order (their slot indices stay
+                # equal); the draft's prompt is computed one-shot when the
+                # target's prefill completes
+                run.draft_task = self.draft_backend.start_prefill(
+                    req.request_id, req.prompt_tokens)
         run.metrics.cached_prompt_tokens = task.cached_tokens
         self.stats["cached_prompt_tokens"] += task.cached_tokens
         return run, task
@@ -684,8 +719,7 @@ class ContinuousBatchingEngine:
             admitted += 1
             if task is None:                  # swap-in restore: no prefill
                 continue
-            logits, n = self.backend.prefill_chunk(task, None)
-            self._account_chunk(run, n)
+            logits, _ = self._prefill_chunk(run, task, None)
             self._finish_ingest(run, logits, finished)
 
     def _prefill_chunked(self, finished: list):
@@ -696,9 +730,8 @@ class ContinuousBatchingEngine:
         for rid, (run, task) in list(self.prefilling.items()):
             if left <= 0:
                 return
-            logits, n = self.backend.prefill_chunk(task, left)
+            logits, n = self._prefill_chunk(run, task, left)
             left -= n
-            self._account_chunk(run, n)
             if logits is not None:
                 del self.prefilling[rid]
                 self._finish_ingest(run, logits, finished)
@@ -711,18 +744,24 @@ class ContinuousBatchingEngine:
             admitted += 1
             if task is None:                  # swap-in restore: no prefill
                 continue
-            logits, n = self.backend.prefill_chunk(task, left)
+            logits, n = self._prefill_chunk(run, task, left)
             left -= n
-            self._account_chunk(run, n)
             if logits is not None:
                 self._finish_ingest(run, logits, finished)
             else:
                 self.prefilling[run.req.request_id] = (run, task)
 
-    def _account_chunk(self, run: _Running, n_tokens: int):
-        self.stats["prefill_tokens"] += n_tokens
+    def _prefill_chunk(self, run: _Running, task: PrefillTask,
+                       budget: int | None):
+        """Compute up to ``budget`` tokens of ``run``'s prefill (all that
+        remain where None) and count them; returns the backend's
+        (logits | None, tokens computed)."""
+        with TraceAnnotation("engine.prefill", request_id=run.req.request_id):
+            logits, n = self.backend.prefill_chunk(task, budget)
+        self.stats["prefill_tokens"] += n
         self.stats["prefill_chunks"] += 1
         run.metrics.prefill_chunks += 1
+        return logits, n
 
     def _finish_prefill(self, run: _Running, logits, finished: list):
         tok = self._sample_one(run.req, logits, step=0)

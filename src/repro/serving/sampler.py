@@ -10,6 +10,11 @@ Three entry points share one implementation:
   inlining inside larger jitted programs (the fused decode step), where
   sampling must happen on device without a separate dispatch.
 
+Every sampler on the device runs under the scope ``sample`` (the jitted
+entry points here; the fused decode and verify paths put their
+sample-and-latch bodies under it), so a profiler trace tells sampling
+apart from the model step by each op's ``op_name``.
+
 Seed folding: the engine derives a per-request ``seed_base =
 (seed * 1_000_003) % SEED_MOD`` once at admission; each step's PRNG seed is
 ``(seed_base + n_generated) % SEED_MOD``. :func:`fold_seeds` reproduces that
@@ -67,16 +72,18 @@ def sample_from_logits(logits, temperature, top_p, seeds):
 @jax.jit
 def sample_tokens(logits, temperature, top_p, seeds):
     """Jitted batch sampler (see :func:`sample_from_logits`)."""
-    return sample_from_logits(logits, temperature, top_p, seeds)
+    with jax.named_scope("sample"):
+        return sample_from_logits(logits, temperature, top_p, seeds)
 
 
 @jax.jit
 def sample_token(logits, temperature, top_p, seed):
     """One sequence's first token from device-resident logits (V,).
     Scalars are weak-typed, so repeated calls don't retrace."""
-    return _sample_one(logits.astype(jnp.float32),
-                       jnp.float32(temperature), jnp.float32(top_p),
-                       jnp.int32(seed))
+    with jax.named_scope("sample"):
+        return _sample_one(logits.astype(jnp.float32),
+                           jnp.float32(temperature), jnp.float32(top_p),
+                           jnp.int32(seed))
 
 
 # ---------------------------------------------------------------------------
