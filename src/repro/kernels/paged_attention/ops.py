@@ -1,15 +1,15 @@
 """Public wrappers for the paged-attention decode kernels.
 
-GQA handling lives here: the kernel grid iterates (batch, kv-head, page)
-and expects the query tensor grouped as (B, KH, G, D) with G = H // KH
-query heads sharing each KV head. Real-TPU lowering requires the (G, D)
-query tile's sublane axis to be a multiple of the dtype's min tile (8 for
-f32, 16 for bf16), which odd groupings (e.g. yi's 56q/8kv -> G=7) and
-small groups (G < 8) violate — so the wrapper pads the group axis up to
-the sublane tile, lets the padded rows compute garbage against the same
-pages, and slices them off. MQA (KH=1) and MHA (G=1) are just the
-endpoints of the same path. The fused-decode wrapper pads the in-flight
-tail the same way along its token axis.
+GQA handling lives here: the kernel grids iterate (batch, kv head or block
+of kv heads, page) and expect the query tensor grouped as (B, KH, G, D)
+with G = H // KH query heads sharing each KV head. Real-TPU lowering
+requires the (G, D) query tile's sublane axis to be a multiple of the
+dtype's min tile (8 for f32, 16 for bf16), which odd groupings (e.g.
+yi's 56q/8kv -> G=7) and small groups (G < 8) violate — so the wrapper
+pads the group axis up to the sublane tile, lets the padded rows compute
+garbage against the same pages, and slices them off. MQA (KH=1) and MHA
+(G=1) are just the endpoints of the same path. The fused-decode wrapper
+pads the in-flight tail the same way along its token axis.
 
 ``interpret`` resolution: ``interpret`` is a static argument of the inner
 jitted functions, so its value must be stable across calls — a per-call

@@ -1,5 +1,7 @@
 """Per-kernel correctness: Pallas (interpret=True) vs pure-jnp oracle,
 swept over shapes and dtypes."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,11 @@ from numpy.testing import assert_allclose
 from repro.kernels.flash_attention.ops import (flash_attention,
                                                paged_flash_prefill)
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.paged_attention.ops import (fused_decode_attention,
+from repro.kernels.paged_attention import kernel as paged_kernel
+from repro.kernels.paged_attention.kernel import (decode_tail_heads_per_block,
+                                                  live_page)
+from repro.kernels.paged_attention.ops import (_group, _pad_axis,
+                                               fused_decode_attention,
                                                fused_decode_attention_sharded,
                                                paged_attention,
                                                paged_attention_sharded)
@@ -198,17 +204,42 @@ def test_paged_attention_empty_context_is_finite():
 # ---------------------------------------------------------------------------
 
 FUSED_CASES = [
-    # B, H, KH, D, page, PPS, NP, Kt
-    (3, 8, 2, 64, 16, 4, 16, 4),
-    (2, 56, 8, 32, 16, 4, 16, 16),    # yi grouping G=7 (sublane-padded)
-    (2, 4, 4, 32, 16, 2, 8, 1),       # MHA, K=1 tail
-    (2, 4, 1, 32, 16, 2, 8, 5),       # MQA, odd tail (pads to sublane)
+    # (B, H, KH, D, page, PPS, NP, Kt), kv heads a block pinned besides the
+    # chosen one (KH at these shapes)
+    ((3, 8, 2, 64, 16, 4, 16, 4), (1,)),
+    ((2, 56, 8, 32, 16, 4, 16, 16), (1, 4)),   # yi grouping G=7 (padded)
+    ((2, 4, 4, 32, 16, 2, 8, 1), (1, 2)),      # MHA, K=1 tail
+    ((2, 4, 1, 32, 16, 2, 8, 5), ()),          # MQA, odd tail (pads)
 ]
+FUSED_PARAMS = [pytest.param(c, None, id=f"case{i}")
+                for i, (c, _) in enumerate(FUSED_CASES)] + \
+    [pytest.param(c, hb, id=f"case{i}-hb{hb}")
+     for i, (c, hbs) in enumerate(FUSED_CASES) for hb in hbs]
 
 
-@pytest.mark.parametrize("case", FUSED_CASES)
+def _decode_tail(q, kp, vp, tables, lens, kt, vt, tail_lens, hb,
+                 monkeypatch):
+    """The decode-tail kernel through its public wrapper (``hb`` None: the
+    chosen block), or at ``hb`` kv heads a block, unjitted and padded as
+    the wrapper pads."""
+    if hb is None:
+        return fused_decode_attention(q, kp, vp, tables, lens, kt, vt,
+                                      tail_lens, interpret=True)
+    monkeypatch.setattr(paged_kernel, "decode_tail_heads_per_block",
+                        lambda *shape: hb)
+    B, H, D = q.shape
+    KH = kp.shape[1]
+    pad = partial(_pad_axis, axis=2,
+                  mult=16 if q.dtype == jnp.bfloat16 else 8)
+    out = paged_kernel.paged_decode_tail_fwd(
+        pad(_group(q, KH)), kp, vp, tables, lens, pad(kt), pad(vt),
+        tail_lens, interpret=True)
+    return out[:, :, :H // KH].reshape(B, H, D)
+
+
+@pytest.mark.parametrize("case,hb", FUSED_PARAMS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_decode_attention(case, dtype):
+def test_fused_decode_attention(case, hb, dtype, monkeypatch):
     B, H, KH, D, page, PPS, NP, Kt = case
     ks = jax.random.split(jax.random.PRNGKey(21), 6)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
@@ -221,12 +252,71 @@ def test_fused_decode_attention(case, dtype):
     tail_lens = (jnp.arange(B, dtype=jnp.int32) * Kt // max(B - 1, 1)) \
         if B > 1 else jnp.full((B,), Kt, jnp.int32)
     tail_lens = jnp.maximum(tail_lens, 1)  # >= 1 like the fused loop
-    out = fused_decode_attention(q, kp, vp, tables, lens, kt, vt,
-                                 tail_lens, interpret=True)
+    out = _decode_tail(q, kp, vp, tables, lens, kt, vt, tail_lens, hb,
+                       monkeypatch)
     ref = fused_decode_attention_ref(q, kp, vp, tables, lens, kt, vt,
                                      tail_lens)
     assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
                     **tol(dtype))
+
+
+@pytest.mark.parametrize("hb", [None, 1])
+def test_fused_decode_attention_never_reads_dead_pages(hb, monkeypatch):
+    """Table entries past the context point at a NaN-filled page, as a
+    freed page may hold anything: the output stays finite and equals the
+    reference over a finite pool."""
+    B, H, KH, D, page, PPS, NP, Kt = 3, 8, 2, 32, 16, 4, 8, 4
+    ks = jax.random.split(jax.random.PRNGKey(23), 5)
+    q = jax.random.normal(ks[0], (B, H, D))
+    kp = jax.random.normal(ks[1], (NP, KH, page, D))
+    vp = jax.random.normal(ks[2], (NP, KH, page, D))
+    kt = jax.random.normal(ks[3], (B, KH, Kt, D))
+    vt = jax.random.normal(ks[4], (B, KH, Kt, D))
+    lens = jnp.asarray([20, 0, 48], jnp.int32)   # mid-page, empty, full
+    tables = jnp.asarray([[1, 2, 0, 0], [0, 0, 0, 0], [3, 4, 5, 0]],
+                         jnp.int32)
+    tail_lens = jnp.asarray([4, 2, 1], jnp.int32)
+    nan_kp, nan_vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    out = _decode_tail(q, nan_kp, nan_vp, tables, lens, kt, vt, tail_lens,
+                       hb, monkeypatch)
+    assert np.isfinite(np.asarray(out)).all()
+    ref = fused_decode_attention_ref(q, kp, vp, tables, lens, kt, vt,
+                                     tail_lens)
+    assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # (KH, padded G, page, D, padded Kt, itemsize)
+    ((20, 16, 128, 128, 16, 2), 20),     # qwen1.5-4b, bf16
+    ((8, 16, 128, 128, 16, 2), 8),       # Yi-34B, G 7
+    ((10, 16, 128, 128, 16, 2), 10),     # Qwen1.5-14B, one of 4 shards
+    ((64, 16, 256, 128, 16, 2), 8),      # past the budget at KH
+    ((2, 8, 16, 64, 8, 4), 2),           # the kernel tests' shapes
+])
+def test_decode_tail_heads_per_block(shape, want):
+    hb = decode_tail_heads_per_block(*shape)
+    assert hb == want and shape[0] % hb == 0
+
+
+def test_decode_tail_heads_per_block_is_largest_that_fits():
+    """Past the budget the chooser takes a proper divisor; pages half as
+    long fit twice the heads, and where no head fits it takes one."""
+    hb = decode_tail_heads_per_block(64, 16, 256, 128, 16, 2)
+    assert hb < 64
+    assert decode_tail_heads_per_block(64, 16, 128, 128, 16, 2) == 2 * hb
+    assert decode_tail_heads_per_block(4, 16, 1 << 16, 128, 16, 2) == 1
+
+
+def test_live_page_clamps_dead_and_tail_steps():
+    page, pps = 64, 4
+    tables = jnp.asarray([[5, 6, 7, 0], [9, 0, 0, 0], [3, 4, 0, 0]],
+                         jnp.int32)
+    clens = jnp.asarray([130, 0, 128], jnp.int32)
+    got = [[int(live_page(tables, clens, b, pi, page_size=page))
+            for pi in range(pps + 1)] for b in range(3)]
+    assert got == [[5, 6, 7, 7, 7],    # 3 live pages, a dead step, the tail
+                   [9, 9, 9, 9, 9],    # no context: tables[b, 0]
+                   [3, 4, 4, 4, 4]]    # context ends on a page boundary
 
 
 def test_fused_decode_attention_equals_materialized_pages():

@@ -16,6 +16,7 @@ so that a single worker is the one that loads it.
 import json
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import paged_flash_prefill
+from repro.kernels.paged_attention.kernel import decode_tail_heads_per_block
 from repro.kernels.paged_attention.ops import (fused_decode_attention,
                                                fused_decode_attention_sharded,
                                                paged_attention,
@@ -123,6 +125,26 @@ def test_fused_decode_tail_compiles_for_v5e(one_chip, page):
     fn = jax.jit(lambda *a: fused_decode_attention(*a, interpret=False))
     args = _decode_args(page, one_chip) + _tail_args(one_chip)
     _assert_kernel_compiled(fn.lower(*args).compile())
+
+
+def test_fused_decode_tail_compiles_at_chat_cell_shapes(one_chip):
+    """The decode-tail kernel at the shapes the qwen1.5-4b chat cell runs:
+    its slots, pages per sequence, pool and steps per call, with every kv
+    head in one block."""
+    eng = json.loads((Path(__file__).parents[1] / "benchmarks" / "chip"
+                      / "configs" / "qwen1.5-4b.json").read_text())["engine"]
+    b, page, kt = (eng["max_slots"], eng["page_size"],
+                   eng["decode_steps_per_sync"])
+    pool = _spec((eng["num_pages"], KH, page, D), one_chip)
+    fn = jax.jit(lambda *a: fused_decode_attention(*a, interpret=False))
+    compiled = fn.lower(
+        _spec((b, H, D), one_chip), pool, pool,
+        _spec((b, eng["max_seq_len"] // page), one_chip, jnp.int32),
+        _spec((b,), one_chip, jnp.int32),
+        _spec((b, KH, kt, D), one_chip), _spec((b, KH, kt, D), one_chip),
+        _spec((b,), one_chip, jnp.int32)).compile()
+    _assert_kernel_compiled(compiled)
+    assert decode_tail_heads_per_block(KH, 16, page, D, 16, 2) == KH
 
 
 @pytest.mark.parametrize("page", [16, 128])
