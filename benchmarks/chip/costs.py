@@ -2,8 +2,8 @@
 computed from shapes. The roofline and utilization readers divide these
 by device time from the trace; nothing here reads a clock.
 
-``m`` is a ``weights.Dims``; ``page`` the KV page size in tokens; bytes
-are of the served dtype (2 for bfloat16).
+``m`` is an architecture's ``Dims`` (``arch/<arch>.py``); ``page`` the KV
+page size in tokens; bytes are of the served dtype (2 for bfloat16).
 """
 from __future__ import annotations
 
@@ -40,3 +40,20 @@ def decode_attn_bytes(m, ctx0: int, steps: int, page: int,
         + 2 * m.H * m.D * itemsize
     tail = 2 * m.KH * m.D * itemsize * steps * (steps + 1) / 2
     return m.L * (steps * per_step + tail)
+
+
+def prefill_attn_flops(m, pos: int, n: int) -> float:
+    """Causal attention of a prefill chunk of n tokens that starts at
+    position ``pos``, all layers: QK^T and PV, 4 H D FLOPs a layer for
+    each (query, key) pair a query sees, itself included."""
+    return 4.0 * m.L * m.H * m.D * (n * pos + n * (n + 1) / 2)
+
+
+def prefill_attn_bytes(m, pos: int, n: int, page: int,
+                       itemsize: int = 2) -> float:
+    """Bytes the paged flash-prefill kernel needs for that chunk, all
+    layers: the K/V pages that cover its context, each read once, and its
+    queries and output."""
+    pages = math.ceil((pos + n) / page)
+    return m.L * (pages * kv_page_bytes(m, page, itemsize)
+                  + 2 * n * m.H * m.D * itemsize)

@@ -1,8 +1,9 @@
 """One run of one cell: set-up, the measured window, the comparison.
 
 Set-up makes the weights on the device from the seed, builds the engine
-that the configuration names, compiles every program shape that the
-cell's traffic can reach (by driving the engine once and the backend's
+that the configuration names (its architecture's ``model_config`` and
+``program_params``, ``arch/<arch>.py``), compiles every program shape that
+the cell's traffic can reach (by driving the engine once and the backend's
 prefill and decode calls at each chunk size, context bucket and decode
 step count). The window then drives the engine as a client would: each
 request is a typed
@@ -35,7 +36,6 @@ import reference
 import spec as spec_mod
 import trace_reduce as TR
 import traffic as traffic_mod
-import weights as W
 
 DRAIN_S = 120.0       # how long requests due in the window may take to
                       # finish after it closes (a 1024-token answer at
@@ -59,6 +59,7 @@ class Req:
     prompt: list = field(repr=False, default=None)
     sent: float | None = None
     admitted: float | None = None      # first start_prefill
+    cached: int = 0                    # prefix-cache hits at admission
     frames: list = field(default_factory=list)   # (time, n_tokens)
     tokens: list = field(default_factory=list)
     finished: float | None = None
@@ -72,7 +73,7 @@ class Req:
 @dataclass
 class Records:
     cell: object
-    dims: W.Dims
+    dims: object                       # the architecture's Dims
     page: int
     chips: int
     peaks: dict | None
@@ -81,6 +82,7 @@ class Records:
     window: tuple = (0.0, 0.0)         # perf_counter bounds of the window
     drain_end: float = 0.0
     requests: list = field(default_factory=list)
+    prefills: list = field(default_factory=list)   # (t, prompt, cached)
     prefill_calls: list = field(default_factory=list)  # (t0, t1, pos, n)
     decode_calls: list = field(default_factory=list)
     # decode_calls: (t0, t1, K asked, K run, [(ctx0, produced), ...])
@@ -149,21 +151,15 @@ def span_factory(on: bool):
     return span
 
 
-def build_engine(cell, m: W.Dims, seed: int):
+def build_engine(cell, m, seed: int):
     """The program under test, with the seed's weights."""
     import jax
-    from repro.configs.base import ModelConfig
     from repro.models import make_model
     from repro.serving.engine import ContinuousBatchingEngine, EngineConfig
 
     c = cell.config
-    mc = ModelConfig(name=c["name"], family="dense", num_layers=m.L,
-                     d_model=m.d, num_heads=m.H, num_kv_heads=m.KH,
-                     head_dim=m.D, d_ff=m.f, vocab_size=m.V, qkv_bias=m.bias,
-                     rope_theta=m.theta, norm_eps=m.eps, param_dtype=m.dtype,
-                     tie_embeddings=bool(c["tie_word_embeddings"]))
-    model = make_model(mc)
-    params = jax.block_until_ready(W.program_params(m, seed))
+    model = make_model(cell.arch.model_config(c, m))
+    params = jax.block_until_ready(cell.arch.program_params(m, seed))
     want = jax.tree.map(lambda s: (s.shape, s.dtype), model.param_shapes())
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     if want != got:
@@ -173,7 +169,7 @@ def build_engine(cell, m: W.Dims, seed: int):
                                     EngineConfig(**c["engine"]))
 
 
-def warm_up(eng, mix: dict, model_name: str) -> int:
+def warm_up(eng, max_context: int, model_name: str) -> int:
     """Compile every program shape the window can reach; returns the
     number of backend calls made. Warm-up prompts start with token 0,
     which the traffic never sends, so their cached pages never match a
@@ -184,7 +180,7 @@ def warm_up(eng, mix: dict, model_name: str) -> int:
     be = eng.backend
     ps, budget, K = ec.page_size, ec.chunked_prefill_budget, \
         ec.decode_steps_per_sync
-    reach = min(ec.max_seq_len - 1, traffic_mod.max_context(mix))
+    reach = min(ec.max_seq_len - 1, max_context)
     # the engine end to end: first-token sampling, K=1 after admission, K
     eng.add_request(InferenceRequest(
         model=model_name, prompt_tokens=[WARM_ID] * (ps + 1),
@@ -248,9 +244,10 @@ def instrument(eng, rec: Records, span):
         t = pc()
         with span("admit"):
             task = start_prefill(seq_id, prompt)
+        rec.prefills.append((t, len(task.prompt), task.cached_tokens))
         r = by_id.get(seq_id)
         if r is not None and r.admitted is None:
-            r.admitted = t
+            r.admitted, r.cached = t, task.cached_tokens
         return task
 
     def w_prefill_chunk(task, budget=None):
@@ -285,15 +282,19 @@ def typed_requests(reqs, model_name: str, mix: dict):
 
 def sample_for_check(rec: Records, seed: int, check: dict) -> list:
     """Finished requests to compare: the one with the most served tokens,
-    the one with the longest prompt, then others drawn from the seed until
-    the sample holds ``sample_tokens`` served tokens or
-    ``sample_requests`` requests."""
+    the one with the longest prompt, the one whose admission found the
+    most prompt tokens in the prefix cache (where any found some), then
+    others drawn from the seed until the sample holds ``sample_tokens``
+    served tokens or ``sample_requests`` requests."""
     done = [r for r in rec.requests if r.finished is not None and r.tokens]
     if not done:
         return []
     rng = np.random.default_rng(seed + 1)
     pick = [max(done, key=lambda r: len(r.tokens)),
             max(done, key=lambda r: r.prompt_len)]
+    hit = max(done, key=lambda r: r.cached)
+    if hit.cached:
+        pick.append(hit)
     pick = list({id(r): r for r in pick}.values())
     for i in rng.permutation(len(done)):
         if (sum(len(r.tokens) for r in pick) >= check["sample_tokens"]
@@ -325,12 +326,13 @@ class Session:
         # test run has no peaks, and the readers of shares find nothing
         self.peaks = peaks_mod.peaks(self.kind) if require_chip \
             else peaks_mod.PEAKS.get(self.kind)
-        self.dims = W.Dims.of(cell.config)
+        self.dims = cell.arch.Dims.of(cell.config)
         self.counter = CompileCounter()
         jax.monitoring.register_event_duration_secs_listener(self.counter)
         self.eng = build_engine(cell, self.dims, seed)
         t = time.time()
-        n = warm_up(self.eng, cell.traffic, cell.config["name"])
+        n = warm_up(self.eng, cell.kind.max_context(cell.traffic),
+                    cell.config["name"])
         log(f"warm-up: {n} backend calls in {time.time() - t:.1f}s")
 
     def window(self, mix: dict, seed: int, seconds: float, trace: bool, *,
@@ -341,7 +343,8 @@ class Session:
         from repro.api import to_inference_request
 
         eng, cell = self.eng, self.cell
-        reqs = traffic_mod.generate(mix, seed, seconds, self.dims.V)
+        reqs = self.spec.traffic_kind(mix).generate(mix, seed, seconds,
+                                                    self.dims.V)
         log(f"traffic: {traffic_mod.describe(reqs)}")
         typed = typed_requests(reqs, cell.config["name"], mix)
         rec = Records(cell=cell, dims=self.dims, page=eng.cfg.page_size,
@@ -494,27 +497,32 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     pairs = [(r.prompt, list(r.tokens)) for r in sample]
     ses.free()
     t = time.time()
-    cmp = reference.compare(ses.dims, seed, pairs, control) if pairs else None
+    cmp = reference.compare(cell.arch, ses.dims, seed, pairs, control) \
+        if pairs else None
     log(f"reference over {len(pairs)} requests, "
         f"{cmp['served_tokens'] if cmp else 0} served tokens: "
         f"{time.time() - t:.1f}s")
 
-    def checks(mean_gap):
-        return {"mean_logit_gap": {
-                    "value": mean_gap,
-                    "limit": check_cfg["max_mean_logit_gap"]},
+    def checks(mean_gap, widest_gap):
+        out = {"mean_logit_gap": {"value": mean_gap,
+                                  "limit": check_cfg["max_mean_logit_gap"]}}
+        if "max_logit_gap" in check_cfg:        # where the cell compares it
+            out["max_logit_gap"] = {"value": widest_gap,
+                                    "limit": check_cfg["max_logit_gap"]}
+        return {**out,
                 "failed_requests": {"value": failed, "limit": 0},
                 "short_requests": {"value": short, "limit": 0},
                 "served_tokens_compared": {
                     "value": cmp["served_tokens"] if cmp else 0,
                     "limit": check_cfg["min_compared_tokens"]}}
 
-    check = checks(cmp["mean_gap"] if cmp else float("inf"))
+    check = checks(*((cmp["mean_gap"], cmp["logit_gap"]) if cmp
+                     else (float("inf"), float("inf"))))
     if cmp is not None:
         log(f"argmax match with the reference: {cmp['argmax_match']:.4f}; "
-            f"widest gap {cmp['logit_gap']} (a reading, not compared)")
+            f"widest gap {cmp['logit_gap']}")
         if control:
-            ctl = checks(cmp["control_mean_gap"])
+            ctl = checks(cmp["control_mean_gap"], cmp["control_gap"])
             out["control"] = {"correct": passes(ctl),
                               "mean_logit_gap": cmp["control_mean_gap"],
                               "widest_gap": cmp["control_gap"]}

@@ -7,22 +7,52 @@ its name alone, so that a new cell, configuration, traffic mix or metric is
 added as a file and an entry, with no edit to an existing file:
 
 - configuration ``<c>``: the JSON file named by its ``file`` entry;
-- traffic mix ``<t>``: ``traffic/<t>.json``;
+- its architecture ``<a>``, the configuration's ``"arch"``:
+  ``arch/<a>.py``, which gives the ``Dims`` the cost functions read (``L``,
+  ``H``, ``KH``, ``D``, ``V``, ``matmul_params()``), the program's
+  ``model_config(config, dims)`` and ``program_params(dims, seed)``, and
+  the plain reference's parts that ``reference.compare`` runs:
+  ``layer_keys(words, dims)``, ``layer_weights(key, m=)``, ``layer(h, w,
+  m=, quant=)``, ``embed(words, toks, m=)`` and ``head(words, h, m=,
+  quant=)``;
+- traffic mix ``<t>``: ``traffic/<t>.json``, and its generator, the mix's
+  ``arrivals.kind`` ``<k>``: ``traffic/kinds/<k>.py``, which gives
+  ``generate(mix, seed, seconds, vocab)`` and ``max_context(mix)``;
 - metric ``<m>``: ``metrics/<m>.py``, or, where no such file exists,
   ``metrics/<base>.py`` with ``<base>`` the part of ``<m>`` before its
   first ``.`` (``idle_share.chat`` and ``idle_share.batch`` share a reader).
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import re
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def load(path: Path, kind: str):
+    """The module of ``path``, executed once per process and path."""
+    if not path.is_file():
+        raise ValueError(f"unknown {kind} {path.stem!r}: no file {path}")
+    digest = hashlib.sha1(str(path.resolve()).encode()).hexdigest()[:12]
+    name = re.sub(r"\W", "_", f"chipbench_{kind}_{path.stem}_{digest}")
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod     # a dataclass looks its module up
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 @dataclass
@@ -35,6 +65,8 @@ class Cell:
     chips: int
     end_to_end: list       # metric entries that this cell reports
     per_layer: list
+    arch: object           # the configuration's architecture module
+    kind: object           # the traffic mix's generator module
 
 
 class Spec:
@@ -54,6 +86,16 @@ class Spec:
     def traffic_path(self, name: str) -> Path:
         return self.bench_dir / "traffic" / f"{name}.json"
 
+    def arch(self, config: dict):
+        """The architecture module that a configuration names."""
+        return load(self.bench_dir / "arch" / f"{config['arch']}.py",
+                    "arch")
+
+    def traffic_kind(self, mix: dict):
+        """The generator module of a traffic mix's arrivals."""
+        return load(self.bench_dir / "traffic" / "kinds"
+                    / f"{mix['arrivals']['kind']}.py", "arrivals")
+
     def metric_path(self, name: str) -> Path:
         exact = self.bench_dir / "metrics" / f"{name}.py"
         if exact.exists():
@@ -71,21 +113,15 @@ class Spec:
             return [m for m in metrics
                     if workload in m.get("workloads", [workload])]
 
+        config = json.loads(self.config_path(w["config"]).read_text())
+        mix = json.loads(self.traffic_path(w["traffic"]).read_text())
         return Cell(
-            name=workload,
-            config=json.loads(self.config_path(w["config"]).read_text()),
-            config_name=w["config"],
-            traffic=json.loads(self.traffic_path(w["traffic"]).read_text()),
-            traffic_name=w["traffic"], chips=int(w["chips"]),
+            name=workload, config=config, config_name=w["config"],
+            traffic=mix, traffic_name=w["traffic"], chips=int(w["chips"]),
             end_to_end=mine(self.bench["end_to_end"]),
-            per_layer=mine(self.bench["per_layer"]))
+            per_layer=mine(self.bench["per_layer"]),
+            arch=self.arch(config), kind=self.traffic_kind(mix))
 
     def reader(self, metric: str):
         """The ``read(rec)`` function of a metric's reader file."""
-        path = self.metric_path(metric)
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{metric.replace('.', '_').replace('-', '_')}",
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load(self.metric_path(metric), "metric").read

@@ -12,7 +12,6 @@ import costs
 import harness
 import spec as spec_mod
 import trace_reduce as TR
-import weights as W
 
 ROOT = Path(__file__).resolve().parents[3]
 
@@ -26,7 +25,7 @@ def sp():
 def dims():
     cfg = json.loads((ROOT / "benchmarks/chip/configs/qwen1.5-4b.json")
                      .read_text())
-    return W.Dims.of(cfg)
+    return spec_mod.Spec(ROOT).arch(cfg).Dims.of(cfg)
 
 
 def records(dims):
@@ -149,3 +148,87 @@ def test_costs_by_hand(dims):
     page = 2 * 128 * 20 * 128 * 2
     one = costs.decode_attn_bytes(m, 300, 1, 128)
     assert one == m.L * (3 * page + 2 * 20 * 128 * 2 + 2 * 20 * 128 * 2)
+
+
+# -- the queued job's readers ----------------------------------------------------
+
+def test_output_tok_s_is_the_job_over_its_makespan(sp, dims):
+    rec = records(dims)
+    # the window opens at 100; the last final frame comes at 160, past the
+    # window's close: the job's makespan is 60 s
+    rec.requests = [req(100.0, [(101.0, 1), (130.0, 9)], 10),
+                    req(100.0, [(102.0, 1), (160.0, 19)], 20)]
+    assert sp.reader("output_tok_s")(rec) == pytest.approx(30 / 60.0)
+    rec.requests.append(req(100.0, [(103.0, 1)], 5, finished=False))
+    assert sp.reader("output_tok_s")(rec) is None
+    rec.requests = []
+    assert sp.reader("output_tok_s")(rec) is None
+
+
+def test_prefix_hit_share_counts_every_admission(sp, dims):
+    rec = records(dims)
+    assert sp.reader("prefix_hit_share.docqa")(rec) is None
+    # (time, prompt tokens, cached tokens); a restore counts again
+    rec.prefills = [(100.5, 4000, 0), (101.0, 4050, 3968),
+                    (101.5, 4020, 3968), (102.0, 1000, 0)]
+    assert sp.reader("prefix_hit_share.docqa")(rec) == \
+        pytest.approx(100.0 * 7936 / 13070)
+    rec.prefills.append((99.0, 5000, 5000))      # before the window
+    assert sp.reader("prefix_hit_share.docqa")(rec) == \
+        pytest.approx(100.0 * 7936 / 13070)
+
+
+def test_prefill_device_time_and_roofline_straddling_the_trace(sp, dims):
+    rec = records(dims)
+    rec.trace_host = (100.0, 101.0)
+    # a chunk whose last quarter falls in the trace, one wholly inside,
+    # and one whose first half does
+    rec.prefill_calls = [(99.4, 100.2, 0, 512), (100.3, 100.5, 512, 512),
+                         (100.8, 101.2, 4096, 64), (100.6, 100.6, 0, 0)]
+    rec.trace = trace_of([(0, 1e9)], ops=[
+        ("_paged_prefill_rows.3", 1e8, 2e6),
+        ("_fused_decode_grouped.4", 3e8, 1e6),
+        ("_paged_prefill_rows.3", 5e8, 3e6)], modules=[
+        ("jit__chunk_prefill_impl", 1e8, 4e7),
+        ("jit_fused_decode", 2e8, 3e7),
+        ("jit__chunk_prefill_impl", 6e8, 2e7)])
+    tokens = 0.25 * 512 + 512 + 0.5 * 64
+    assert sp.reader("prefill_ms_per_ktok.docqa")(rec) == \
+        pytest.approx(0.06 * 1e6 / tokens)
+
+    def least(pos, n):
+        return max(costs.prefill_attn_flops(dims, pos, n) / 100e12,
+                   costs.prefill_attn_bytes(dims, pos, n, 128) / 1e12)
+    want = 0.25 * least(0, 512) + least(512, 512) + 0.5 * least(4096, 64)
+    assert sp.reader("prefill_attn_roofline.docqa")(rec) == \
+        pytest.approx(100.0 * want / 5e-3)
+    # the short chunk at a long context is bound by its bytes, the others
+    # by their FLOPs
+    assert costs.prefill_attn_bytes(dims, 4096, 64, 128) / 1e12 > \
+        costs.prefill_attn_flops(dims, 4096, 64) / 100e12
+    assert costs.prefill_attn_flops(dims, 512, 512) / 100e12 > \
+        costs.prefill_attn_bytes(dims, 512, 512, 128) / 1e12
+
+
+def test_prefill_readers_find_nothing_without_a_trace_or_kernel(sp, dims):
+    rec = records(dims)
+    rec.prefill_calls = [(100.3, 100.5, 0, 512)]
+    for name in ("prefill_ms_per_ktok.docqa", "prefill_attn_roofline.docqa"):
+        assert sp.reader(name)(rec) is None, name
+    rec.trace_host = (100.0, 101.0)
+    rec.trace = trace_of([(0, 1e9)], ops=[("fusion.1", 1e8, 1e6)])
+    for name in ("prefill_ms_per_ktok.docqa", "prefill_attn_roofline.docqa"):
+        assert sp.reader(name)(rec) is None, name
+
+
+def test_prefill_costs_by_hand(dims):
+    m = dims
+    # a chunk of 3 tokens at position 100 sees 101, 102 and 103 keys
+    assert costs.prefill_attn_flops(m, 100, 3) == \
+        4 * m.L * m.H * m.D * (101 + 102 + 103)
+    # its attention is the attention part of chunk_flops
+    assert costs.chunk_flops(m, 100, 3) - \
+        2 * m.matmul_params() * 3 == costs.prefill_attn_flops(m, 100, 3)
+    # 103 positions: one page of K and V a layer, and q and out of 3 rows
+    assert costs.prefill_attn_bytes(m, 100, 3, 128) == \
+        m.L * (costs.kv_page_bytes(m, 128) + 2 * 3 * 20 * 128 * 2)

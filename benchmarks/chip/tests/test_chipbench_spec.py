@@ -8,8 +8,6 @@ from pathlib import Path
 import pytest
 
 import spec as spec_mod
-import traffic
-import weights as W
 
 ROOT = Path(__file__).resolve().parents[3]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -57,9 +55,9 @@ def test_every_cell_resolves_to_its_files():
         cell = sp.cell(w["name"])
         assert sp.traffic_path(w["traffic"]).is_file()
         vocab = cell.config["vocab_size"]
-        assert traffic.max_context(cell.traffic) < \
+        assert cell.kind.max_context(cell.traffic) < \
             cell.config["engine"]["max_seq_len"]
-        assert traffic.generate(cell.traffic, 1, 2, vocab)
+        assert cell.kind.generate(cell.traffic, 1, 2, vocab)
         e2e = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in e2e and len(e2e) >= 2, w
         assert cell.per_layer, w
@@ -84,7 +82,7 @@ def test_configs_resolve_and_list_their_cuts():
             assert key in data and not WIDTH.search(key), key
             assert key in data.get("published", {}), key
         assert c["source"].startswith("https://")
-        m = W.Dims.of(data)
+        m = spec_mod.Spec(ROOT).arch(data).Dims.of(data)
         assert m.H % m.KH == 0 and m.H * m.D == m.d
 
 
@@ -111,9 +109,10 @@ def test_metrics_list_cells_that_exist():
     assert all(len(v) == 1 for v in layers.values()), layers
 
 
-@pytest.mark.parametrize("name", ["chat"])
+@pytest.mark.parametrize("name", ["chat", "docqa"])
 def test_traffic_files_parse(name):
     mix = json.loads((ROOT / "benchmarks/chip/traffic" / f"{name}.json")
                      .read_text())
-    assert mix["arrivals"]["kind"] == "poisson"
+    kind = spec_mod.Spec(ROOT).traffic_kind(mix)
+    assert callable(kind.generate) and callable(kind.max_context)
     assert mix["temperature"] == 0.0       # greedy, as the check needs
